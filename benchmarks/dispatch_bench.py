@@ -475,6 +475,8 @@ def run(smoke=True):
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes for CI (default unless --full)")

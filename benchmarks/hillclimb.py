@@ -91,6 +91,7 @@ VARIANTS = {
 _RUN_TEMPLATE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU dry-run: never takes a chip
 import json, sys
 sys.path.insert(0, "src")
 import jax.numpy as jnp

@@ -262,16 +262,6 @@ class VDMSAsyncEngine:
             raise ValueError(
                 f"num_device_workers must be >= 1, got "
                 f"{num_device_workers!r}")
-        device_pool = None
-        if device_backend:
-            # resolve the device set HERE, before any pool/loop thread
-            # exists: jax raises on a platform string this host does not
-            # have, and that failure must not leak running threads
-            import jax
-            if isinstance(device_backend, str) and device_backend != "auto":
-                device_pool = jax.devices(device_backend)
-            else:
-                device_pool = jax.devices()
         if dispatch == "static":
             if cost_overrides:
                 # a forced regime with no router would be silently inert
@@ -320,6 +310,42 @@ class VDMSAsyncEngine:
                     raise ValueError(
                         f"{name} requires breaker_enabled (there is no "
                         f"circuit breaker to parameterize without it)")
+        # the cost tracker is shared by the router, the batcher and the
+        # device workers, which are built next
+        self.cost_tracker = (OpCostTracker() if dispatch != "static"
+                             else None)
+        self.device_backend = None
+        if device_backend:
+            # built HERE, before any pool/loop thread exists: jax raises
+            # on a platform string this host does not have, and each
+            # worker's calibration probe raises on a device that fails a
+            # round trip — neither failure may leak running threads.
+            # device_backend=True/"auto" targets jax's default device; a
+            # platform string ("cpu", "tpu") pins one.  Fusion defaults
+            # ON; one worker per visible device unless num_device_workers
+            # pins the count (a single worker stays a plain DeviceBackend
+            # — no wrapper indirection on the common path).
+            import jax
+            from repro.query.device_backend import (DeviceBackend,
+                                                    MultiDeviceBackend)
+            if isinstance(device_backend, str) and device_backend != "auto":
+                device_pool = jax.devices(device_backend)
+            else:
+                device_pool = jax.devices()
+            fuse = (device_fuse_segments
+                    if device_fuse_segments is not None else True)
+            count = (num_device_workers if num_device_workers is not None
+                     else len(device_pool))
+            workers = [
+                DeviceBackend(
+                    batch_size=device_batch_size,
+                    max_wait_s=device_max_wait_ms / 1000.0,
+                    tracker=self.cost_tracker,
+                    device=device_pool[i % len(device_pool)],
+                    fuse_segments=fuse)
+                for i in range(count)]
+            self.device_backend = (workers[0] if count == 1
+                                   else MultiDeviceBackend(workers))
         self.health = None
         self.fallback = fallback
         if breaker_enabled:
@@ -371,56 +397,23 @@ class VDMSAsyncEngine:
         # promoted to a backend, and a router the planner consults at
         # expand time (repro.query.dispatch)
         self.dispatch = dispatch
-        self.cost_tracker = None
         self.router = None
         self.batcher_backend = None
-        self.device_backend = None
-        if dispatch != "static":
-            self.cost_tracker = OpCostTracker()
-            if dispatch == "cost":
-                # deferred: serving.batcher pulls in the model stack,
-                # which a non-batcher engine never needs
-                from repro.serving.batcher import UDFBatcherBackend
-                self.batcher_backend = UDFBatcherBackend(
-                    group_size=batcher_group_size,
-                    max_wait_s=batcher_max_wait_ms / 1000.0,
-                    tracker=self.cost_tracker)
-                if device_backend:
-                    # deferred for the same reason: the device executor
-                    # pulls in jax device plumbing a CPU-only engine
-                    # never needs.  device_backend=True/"auto" targets
-                    # jax's default device; a platform string ("cpu",
-                    # "gpu", "tpu") pins one (resolved above, pre-thread).
-                    # Fusion defaults ON; one worker per visible device
-                    # unless num_device_workers pins the count (a single
-                    # worker stays a plain DeviceBackend — no wrapper
-                    # indirection on the common path).
-                    from repro.query.device_backend import (
-                        DeviceBackend, MultiDeviceBackend)
-                    fuse = (device_fuse_segments
-                            if device_fuse_segments is not None else True)
-                    count = (num_device_workers
-                             if num_device_workers is not None
-                             else len(device_pool))
-                    workers = [
-                        DeviceBackend(
-                            batch_size=device_batch_size,
-                            max_wait_s=device_max_wait_ms / 1000.0,
-                            tracker=self.cost_tracker,
-                            device=device_pool[i % len(device_pool)],
-                            fuse_segments=fuse)
-                        for i in range(count)]
-                    self.device_backend = (
-                        workers[0] if count == 1
-                        else MultiDeviceBackend(workers))
-                if fault_injector is not None:
-                    # offload backends consult the injector per group
-                    # run (site "backend:<name>"); remote servers got
-                    # theirs via the pool above
-                    self.batcher_backend.fault_injector = fault_injector
-                    if self.device_backend is not None:
-                        self.device_backend.fault_injector = \
-                            fault_injector
+        if dispatch == "cost":
+            # deferred: serving.batcher pulls in the model stack, which a
+            # non-batcher engine never needs
+            from repro.serving.batcher import UDFBatcherBackend
+            self.batcher_backend = UDFBatcherBackend(
+                group_size=batcher_group_size,
+                max_wait_s=batcher_max_wait_ms / 1000.0,
+                tracker=self.cost_tracker)
+            if fault_injector is not None:
+                # offload backends consult the injector per group run
+                # (site "backend:<name>"); remote servers got theirs via
+                # the pool above
+                self.batcher_backend.fault_injector = fault_injector
+                if self.device_backend is not None:
+                    self.device_backend.fault_injector = fault_injector
         self.loop = EventLoop(self.pool, self.erd,
                               fuse_native=fuse_native,
                               batch_remote=batch_remote,

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -86,7 +85,7 @@ def make_compressed_grad_reducer(mesh: Mesh, axis: str = "data"):
                 flat = jnp.concatenate(
                     [flat, jnp.zeros((n, pad), jnp.float32)], axis=1)
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 functools.partial(compressed_psum_int8, axis_name=axis),
                 mesh=mesh,
                 in_specs=P(axis, None),

@@ -2,13 +2,10 @@
 
 Layout per kernel ``<name>``:
 - ``<name>.py`` — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling
-- ``ops.py``    — jit'd public wrappers with impl dispatch
-- ``ref.py``    — pure-jnp oracles (also the CPU execution path)
+- ``ops.py``    — public wrappers with impl dispatch
+- ``ref.py``    — pure-jnp oracles (also the non-TPU execution path)
+
+The package re-exports nothing: a re-exported wrapper named like its
+kernel module (``gaussian_blur``) would shadow the module, and
+``from repro.kernels import gaussian_blur`` would return the function.
 """
-from repro.kernels.ops import (  # noqa: F401
-    flash_attention,
-    decode_attention,
-    gaussian_blur,
-    rwkv6_scan,
-    mamba2_ssd,
-)

@@ -16,12 +16,15 @@ so the fused op is two MXU matmuls plus a VPU affine — no gather, no
 intermediate (H_res, W_res) image ever materializes, and the cropped
 rows of the resize are never computed at all.
 
-The kernel runs one image per grid step: grid = (N,), each step sees
-(1, H_in, W_in, C) plus the two small matrices (replicated across the
-grid).  VMEM at 1080p→512² crop: 1920·1080·3·4B ≈ 24 MB is too big for
-one block, but this kernel targets the query engine's preprocessing
-regime (≤ 256² inputs after storage-side thumbnailing), where the
-working set is < 2 MB.
+The kernel runs one channel plane per grid step: the wrapper moves
+channels off the lane axis — (N, H, W, C) becomes channel-planar
+(N, C, H, W), so W sits on the lanes — and grid = (N, C); each step sees
+one (H_in, W_in) plane plus the two small matrices (replicated across
+the grid) and computes two plain 2-D matmuls, ``Ry @ plane @ Rx^T``.
+The matrices are host constants computed once per shape, never traced
+values.  VMEM at 250x250 -> 224x224: plane, Ry, Rx^T and output are
+each ~0.2 MB, under 2 MB double-buffered; a 1080p plane (8 MB) would
+need row bands.
 
 ``fused_resize_crop_normalize`` is the public entry; ``impl="auto"``
 lowers to Pallas on TPU and to the composed reference ops elsewhere, so
@@ -40,10 +43,15 @@ import numpy as np
 def resize_matrix(n_in: int, n_out: int, method: str = "bilinear"):
     """Exact (n_out, n_in) interpolation matrix of ``jax.image.resize``
     along one axis, antialiasing taps included — probed by resizing the
-    identity (resize is separable and linear per axis)."""
-    eye = jnp.eye(n_in, dtype=jnp.float32)
-    # resize axis 0 only: axis 1 keeps its size (scale 1 == identity)
-    return np.asarray(jax.image.resize(eye, (n_out, n_in), method=method))
+    identity (resize is separable and linear per axis).  Evaluated
+    eagerly on the host CPU, also when called while a jit is tracing, so
+    the matrix is a numpy constant of the program."""
+    cpu = jax.devices("cpu")[0]
+    with jax.ensure_compile_time_eval():
+        eye = jax.device_put(np.eye(n_in, dtype=np.float32), cpu)
+        # resize axis 0 only: axis 1 keeps its size (scale 1 == identity)
+        return np.asarray(jax.image.resize(eye, (n_out, n_in),
+                                           method=method))
 
 
 @functools.lru_cache(maxsize=64)
@@ -61,19 +69,14 @@ def _cropped_matrices(h_in: int, w_in: int, h_res: int, w_res: int,
     return ry, rx
 
 
-def _preprocess_kernel(img_ref, ry_ref, rx_ref, o_ref, *, mean, std):
-    img = img_ref[0].astype(jnp.float32)            # (Hi, Wi, C)
-    hi, wi, c = img.shape
-    ry = ry_ref[...]                                # (Hc, Hi)
-    rx = rx_ref[...]                                # (Wc, Wi)
-    tmp = jnp.dot(ry, img.reshape(hi, wi * c),
+def _preprocess_kernel(img_ref, ry_ref, rxt_ref, o_ref, *, mean, std):
+    plane = img_ref[0, 0].astype(jnp.float32)       # (Hi, Wi)
+    hp = jax.lax.Precision.HIGHEST                   # f32 MXU passes
+    tmp = jnp.dot(ry_ref[...], plane, precision=hp,  # (Hc, Hi) @ (Hi, Wi)
                   preferred_element_type=jnp.float32)
-    tmp = tmp.reshape(-1, wi, c)                    # (Hc, Wi, C)
-    # contract Wi against rx: (Hc, Wi, C) x (Wc, Wi) -> (Hc, C, Wc)
-    out = jax.lax.dot_general(tmp, rx, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    out = out.transpose(0, 2, 1)                    # (Hc, Wc, C)
-    o_ref[0] = ((out - mean) / std).astype(o_ref.dtype)
+    out = jnp.dot(tmp, rxt_ref[...], precision=hp,   # (Hc, Wi) @ (Wi, Wc)
+                  preferred_element_type=jnp.float32)
+    o_ref[0, 0] = ((out - mean) / std).astype(o_ref.dtype)
 
 
 def fused_resize_crop_normalize_pallas(
@@ -99,20 +102,22 @@ def fused_resize_crop_normalize_pallas(
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",))
+            dimension_semantics=("parallel", "parallel"))
     out = pl.pallas_call(
         kernel,
-        grid=(n,),
+        grid=(n, c),
         in_specs=[
-            pl.BlockSpec((1, hi, wi, c), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((hc, hi), lambda i: (0, 0)),
-            pl.BlockSpec((wc, wi), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1, hi, wi), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((hc, hi), lambda i, j: (0, 0)),
+            pl.BlockSpec((wi, wc), lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, hc, wc, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, hc, wc, c), img.dtype),
+        out_specs=pl.BlockSpec((1, 1, hc, wc), lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, c, hc, wc), img.dtype),
         interpret=interpret,
         **kwargs,
-    )(img, jnp.asarray(ry), jnp.asarray(rx))
+    )(jnp.transpose(img, (0, 3, 1, 2)), jnp.asarray(ry),
+      jnp.asarray(np.ascontiguousarray(rx.T)))
+    out = jnp.transpose(out, (0, 2, 3, 1))          # (N, Hc, Wc, C)
     return out[0] if squeeze else out
 
 

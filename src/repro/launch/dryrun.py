@@ -23,6 +23,7 @@ import os
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"   # placeholder devices: never a chip
 
 import argparse
 import dataclasses
@@ -173,15 +174,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, mesh=None,
             t_compile = time.time() - t0 - t_lower
 
         ca = compiled.cost_analysis() or {}
-        mem = None
-        try:
-            ma = compiled.memory_analysis()
-            if ma is not None:
-                mem = {k: int(getattr(ma, k)) for k in dir(ma)
-                       if not k.startswith("_")
-                       and isinstance(getattr(ma, k, None), int)}
-        except Exception:
-            mem = None
+        ma = compiled.memory_analysis()
+        mem = {k: getattr(ma, k) for k in dir(ma)
+               if not k.startswith("_") and isinstance(getattr(ma, k), int)}
         hlo = compiled.as_text()
         costs = hlo_costs.analyze_hlo(hlo)
 

@@ -12,19 +12,27 @@ XLA_FLAGS before importing anything).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    # Auto axes: the sharding rules place arrays with
+    # with_sharding_constraint, which Explicit axes (make_mesh's default)
+    # refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally-available devices (tests/examples)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

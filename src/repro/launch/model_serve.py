@@ -75,6 +75,8 @@ def run(arch: str, *, reduced=True, requests=16, prompt_len=32, gen=16,
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true", default=True)

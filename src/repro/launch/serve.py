@@ -47,6 +47,8 @@ def build_engine(args):
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="serve the VDMS-Async wire protocol")
     ap.add_argument("--host", default="127.0.0.1")

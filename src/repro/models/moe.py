@@ -21,7 +21,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -139,13 +138,13 @@ def apply_moe_ep_shardmap(p, x, *, cfg: ArchConfig, sh: ShardingCtx,
         return y.reshape(Bl, S, D), aux
 
     batch_spec = P(dp_axes if dp_axes else None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(batch_spec, P(None, None),
                   P("model", None, "data"), P("model", None, "data"),
                   P("model", "data", None)),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

@@ -4,11 +4,11 @@ protocol" + "device-resident query compilation").
 The dispatch layer's three other backends all execute on CPU threads;
 this module adds the backend whose cost structure is qualitatively
 different: a **device executor** that runs compute/model ops as
-jit-compiled JAX functions on an accelerator (GPU/TPU when present —
-this container's jax is CPU-only, so the same code path degrades to a
-"CPU-as-device" executor: one worker thread owning jit-compiled,
-micro-batched XLA execution, which still amortizes per-op Python/eager
-dispatch overhead over the batch).
+jit-compiled JAX functions on the device it is given.  The engine gives
+it the devices of the platform named by ``device_backend`` ("tpu"
+raises where there is none); kernels are chosen by that device's
+platform, so a worker pinned to the CPU (as the tests do) runs the jnp
+reference ops and never a Pallas call.
 
 Execution model: one worker thread per device pulls entities off an
 inbox, collects a micro-batch of up to ``batch_size`` entities held at
@@ -177,23 +177,24 @@ class DeviceCostModel:
 
     def calibrate(self, device, probe_bytes: int = 1 << 20):
         """Measure real h2d/d2h bandwidth with one probe round trip.
-        Failures (no device, backend quirks) leave the defaults."""
+        A failed probe raises: a device that cannot take a 1 MiB round
+        trip cannot serve a segment either, and defaults would hide it."""
         import jax
-        try:
-            probe = np.ones(probe_bytes // 4, np.float32)
-            t0 = time.monotonic()
-            on_dev = jax.device_put(probe, device)
-            on_dev.block_until_ready()
-            t1 = time.monotonic()
-            np.asarray(jax.device_get(on_dev))
-            t2 = time.monotonic()
-            if t1 - t0 > 0:
-                self.h2d_bytes_s = probe.nbytes / (t1 - t0)
-            if t2 - t1 > 0:
-                self.d2h_bytes_s = probe.nbytes / (t2 - t1)
-            self.calibrated = True
-        except Exception:  # noqa: BLE001 — calibration is best-effort
-            pass
+        probe = np.ones(probe_bytes // 4, np.float32)
+        t0 = time.monotonic()
+        on_dev = jax.device_put(probe, device)
+        on_dev.block_until_ready()
+        t1 = time.monotonic()
+        back = np.asarray(jax.device_get(on_dev))
+        t2 = time.monotonic()
+        if not np.array_equal(back, probe):
+            raise RuntimeError(f"calibration probe on {device} came back "
+                               f"altered")
+        if t1 - t0 > 0:
+            self.h2d_bytes_s = probe.nbytes / (t1 - t0)
+        if t2 - t1 > 0:
+            self.d2h_bytes_s = probe.nbytes / (t2 - t1)
+        self.calibrated = True
 
     def transfer_s(self, nbytes: float, batch: int = 1) -> float:
         """Seconds to move one entity's payload through the device,

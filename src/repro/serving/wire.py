@@ -71,7 +71,9 @@ def to_jsonable(value: Any) -> Any:
     """JSON-encode a result payload: ndarrays (at any nesting depth in
     dicts/lists) become the ``__nd__`` coding; scalars pass through."""
     if isinstance(value, np.ndarray):
-        arr = np.ascontiguousarray(value)
+        # np.asarray, not np.ascontiguousarray: the latter returns at
+        # least 1-d and would turn a 0-d array into shape (1,)
+        arr = np.asarray(value, order="C")
         return {"__nd__": True, "dtype": arr.dtype.str,
                 "shape": list(arr.shape),
                 "b64": base64.b64encode(arr.tobytes()).decode("ascii")}

@@ -1,7 +1,11 @@
 """Entity blob store: in-memory LRU with optional disk spill (npz).
 
 Plays the role of VDMS's TDB/visual-data store: decouples entity
-payloads from metadata so the engine passes pointers, not pixels."""
+payloads from metadata so the engine passes pointers, not pixels.
+
+Without a ``spill_dir`` the memory cap is a hard limit: a ``put`` that
+would exceed it raises :class:`BlobStoreFull` and stores nothing, since
+evicting would silently drop a blob whose write was acknowledged."""
 from __future__ import annotations
 
 import collections
@@ -9,6 +13,11 @@ import os
 import threading
 
 import numpy as np
+
+
+class BlobStoreFull(MemoryError):
+    """A ``put`` past ``capacity_bytes`` on a store with no spill
+    directory to evict into."""
 
 
 class BlobStore:
@@ -28,7 +37,15 @@ class BlobStore:
     def put(self, key: str, arr) -> None:
         arr = np.asarray(arr)
         with self._lock:
-            if key in self._mem:
+            old = self._mem.get(key)
+            after = self._bytes - (old.nbytes if old is not None else 0) \
+                + arr.nbytes
+            if not self.spill_dir and after > self.capacity:
+                raise BlobStoreFull(
+                    f"put({key!r}) needs {after} bytes, over the "
+                    f"{self.capacity}-byte cap, and there is no spill_dir "
+                    f"to evict into")
+            if old is not None:
                 self._bytes -= self._mem.pop(key).nbytes
             self._mem[key] = arr
             self._bytes += arr.nbytes
@@ -58,13 +75,13 @@ class BlobStore:
             os.remove(path)
 
     def _evict_locked(self):
+        # only reached over capacity with a spill_dir (put refuses
+        # otherwise), so every evicted blob lands on disk
         while self._bytes > self.capacity and len(self._mem) > 1:
             key, arr = self._mem.popitem(last=False)
             self._bytes -= arr.nbytes
-            path = self._path(key)
-            if path:
-                np.savez_compressed(path, a=arr)
-                self.spills += 1
+            np.savez_compressed(self._path(key), a=arr)
+            self.spills += 1
 
     def _path(self, key: str) -> str | None:
         if not self.spill_dir:

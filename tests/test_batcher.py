@@ -64,6 +64,7 @@ def test_elastic_remesh_roundtrip():
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.elastic import remesh_tree, shrink_batch_for_mesh

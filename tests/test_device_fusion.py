@@ -462,3 +462,36 @@ def test_fused_preprocess_pallas_matches_ref_in_interpret_mode():
     interp2 = np.asarray(fused_preprocess(img, impl="pallas_interpret",
                                           **kw_oob))
     np.testing.assert_allclose(ref2, interp2, rtol=1e-5, atol=1e-5)
+
+
+def test_fused_preprocess_jitted_pallas_matches_ref():
+    """Under jit (as the device segment program calls it) the kernel's
+    interpolation matrices are host constants, not tracers."""
+    import jax
+    from repro.kernels.ops import fused_preprocess
+    from repro.kernels.preprocess import fused_resize_crop_normalize_ref
+    rng = np.random.default_rng(2)
+    img = rng.uniform(0, 1, (2, 32, 28, 3)).astype(np.float32)
+    kw = dict(resize_h=24, resize_w=20, crop_x=2, crop_y=3,
+              crop_w=12, crop_h=10, mean=0.4, std=0.25)
+    got = jax.jit(lambda x: fused_preprocess(
+        x, impl="pallas_interpret", **kw))(img)
+    want = fused_resize_crop_normalize_ref(img, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_fused_preprocess_pallas_at_lfw_shape():
+    """250x250x3 faces -> resize 256 -> crop 224 at (16, 16) ->
+    normalize: the shape the chip smoke runs."""
+    from repro.kernels.ops import fused_preprocess
+    from repro.kernels.preprocess import fused_resize_crop_normalize_ref
+    rng = np.random.default_rng(3)
+    img = rng.uniform(0, 1, (2, 250, 250, 3)).astype(np.float32)
+    kw = dict(resize_h=256, resize_w=256, crop_x=16, crop_y=16,
+              crop_w=224, crop_h=224, mean=0.5, std=0.25)
+    got = fused_preprocess(img, impl="pallas_interpret", **kw)
+    want = fused_resize_crop_normalize_ref(img, **kw)
+    assert got.shape == (2, 224, 224, 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

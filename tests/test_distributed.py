@@ -13,7 +13,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(code: str, devices: int = 8, timeout: int = 300) -> str:
     pre = (f'import os; os.environ["XLA_FLAGS"] = '
-           f'"--xla_force_host_platform_device_count={devices}"\n'
+           f'"--xla_force_host_platform_device_count={devices}"; '
+           f'os.environ["JAX_PLATFORMS"] = "cpu"\n'
            f'import sys; sys.path.insert(0, "src")\n')
     out = subprocess.run([sys.executable, "-c", pre + code],
                          capture_output=True, text=True, cwd=ROOT,

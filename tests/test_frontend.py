@@ -107,6 +107,18 @@ def _serve(engine):
     return WireFrontend(engine).start()
 
 
+@pytest.mark.parametrize("shape", [(), (1,), (2, 3)])
+def test_wire_ndarray_coding_keeps_shape(shape):
+    """Pinned counterexample of the chunking property: a 0-d array must
+    come back 0-d, not as shape (1,)."""
+    from repro.serving.wire import from_jsonable, to_jsonable
+    arr = np.arange(int(np.prod(shape)), dtype=np.float32).reshape(shape)
+    back = from_jsonable(json.loads(json.dumps(to_jsonable({"a": arr}))))
+    assert back["a"].shape == shape
+    assert back["a"].dtype == arr.dtype
+    assert np.array_equal(back["a"], arr)
+
+
 # ============================================ golden conformance suite
 def test_golden_submit_stream_complete():
     eng = VDMSAsyncEngine(**DET)

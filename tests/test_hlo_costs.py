@@ -83,6 +83,7 @@ def test_collective_bytes_counted():
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp, sys
 sys.path.insert(0, "src")
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -1,6 +1,9 @@
 """Per-kernel validation: Pallas (interpret=True) and chunked-jnp paths
 against the pure-jnp oracles, swept over shapes/dtypes, plus custom-VJP
 gradient checks."""
+import functools
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -90,6 +93,51 @@ def test_gaussian_blur_pallas(shape, ksize, sigma):
     want = ref.gaussian_blur_ref(img, ksize, sigma)
     got = gaussian_blur_pallas(img, ksize, sigma, block_rows=8, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_gaussian_blur_pallas_at_lfw_shape():
+    """The channel-planar kernel at the LFW face shape, with the default
+    (128-row) bands: two bands per plane, the second one ragged."""
+    img = jax.random.uniform(KEY, (2, 250, 250, 3))
+    want = ref.gaussian_blur_ref(img, 5, 1.5)
+    got = gaussian_blur_pallas(img, 5, 1.5, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_gaussian_blur_wrapper_reaches_kernel_in_fresh_process():
+    """``ops.gaussian_blur`` imports the kernel module by name; a fresh
+    interpreter (no test module has imported the submodule first) must
+    find the module, not a same-named function re-exported by the
+    package."""
+    import subprocess
+    import sys
+    code = (
+        "import numpy as np\n"
+        "from repro.kernels import ops, ref\n"
+        "img = np.random.default_rng(0).uniform(0, 1, (1, 16, 16, 3))"
+        ".astype(np.float32)\n"
+        "got = ops.gaussian_blur(img, 5, 1.5, impl='pallas_interpret')\n"
+        "want = ref.gaussian_blur_ref(img, 5, 1.5)\n"
+        "assert np.allclose(got, want, atol=1e-5)\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+@pytest.mark.parametrize("placed", ["default", "jit"])
+def test_gaussian_blur_auto_follows_lowering_platform(placed):
+    """impl="auto" picks by the platform the work is lowered for: on a
+    CPU device that is the jnp reference."""
+    from repro.kernels import ops
+    img = jax.random.uniform(KEY, (2, 24, 20, 3))
+    fn = functools.partial(ops.gaussian_blur, ksize=5, sigma_x=1.5)
+    got = jax.jit(fn)(img) if placed == "jit" else fn(img)
+    want = ref.gaussian_blur_ref(img, 5, 1.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
 
 
 def test_gaussian_blur_preserves_mean():

@@ -7,7 +7,7 @@ import pytest
 
 from repro.dataio import ShardedLoader, lm_token_stream, synthetic_faces
 from repro.query.language import parse_query
-from repro.storage.store import BlobStore
+from repro.storage.store import BlobStore, BlobStoreFull
 
 
 def test_blobstore_roundtrip():
@@ -31,6 +31,22 @@ def test_blobstore_spills_to_disk_and_reloads():
         assert s.spills > 0
         for k, a in arrs.items():  # everything still retrievable
             np.testing.assert_array_equal(s.get(k), a)
+
+
+def test_blobstore_without_spill_refuses_put_past_capacity():
+    """No spill directory: a put past the cap fails loudly and every
+    acknowledged blob stays readable (nothing is evicted)."""
+    s = BlobStore(capacity_bytes=4096)
+    arrs = {f"k{i}": np.full((16, 16), i, np.float32) for i in range(4)}
+    for k, a in arrs.items():
+        s.put(k, a)
+    with pytest.raises(BlobStoreFull):
+        s.put("k4", np.zeros((16, 16), np.float32))
+    assert "k4" not in s
+    for k, a in arrs.items():
+        np.testing.assert_array_equal(s.get(k), a)
+    s.put("k0", np.ones((16, 16), np.float32))   # same-size overwrite fits
+    np.testing.assert_array_equal(s.get("k0"), 1.0)
 
 
 def test_parse_query_validates():
