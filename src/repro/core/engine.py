@@ -30,6 +30,7 @@ from repro.core.event_loop import EventLoop
 from repro.core.remote import RemoteServerPool, TransportModel
 from repro.core.result_cache import ResultCache
 from repro.core.session import QueryFuture, QuerySession
+from repro.core.trace import Tracer
 from repro.query.admission import AdmissionController, OverloadError
 from repro.query.dispatch import (BackendRouter, NativeBackend, OpCostTracker,
                                   RemoteBackend, StaticRouter,
@@ -221,6 +222,9 @@ class VDMSAsyncEngine:
                         f"{name} requires admission='queue' or 'shed' "
                         f"(admission='none' builds no controller to "
                         f"consult it)")
+        # one tracer for every layer an entity passes through
+        # (utilization()["trace"])
+        self.tracer = Tracer()
         # built pre-thread: a malformed admission knob (cap <= 0, bad
         # queue cap, malformed tenant table, cost knobs half-set) must
         # raise before any pool/loop thread exists
@@ -232,7 +236,8 @@ class VDMSAsyncEngine:
                 tenant_weights=admission_tenants,
                 tenant_default_weight=admission_tenant_default_weight,
                 cost_aware=admission_cost_aware,
-                cost_cap_s=admission_cost_cap_s)
+                cost_cap_s=admission_cost_cap_s,
+                tracer=self.tracer)
             if admission != "none" else None)
         self.admission = admission
         if dispatch not in ("static", "cost", "native"):
@@ -342,7 +347,8 @@ class VDMSAsyncEngine:
                     max_wait_s=device_max_wait_ms / 1000.0,
                     tracker=self.cost_tracker,
                     device=device_pool[i % len(device_pool)],
-                    fuse_segments=fuse)
+                    fuse_segments=fuse,
+                    tracer=self.tracer)
                 for i in range(count)]
             self.device_backend = (workers[0] if count == 1
                                    else MultiDeviceBackend(workers))
@@ -376,7 +382,8 @@ class VDMSAsyncEngine:
             retry_backoff_base_s=retry_backoff_base_s,
             retry_backoff_max_s=retry_backoff_max_s,
             heartbeat_timeout_s=heartbeat_timeout_s,
-            fault_injector=fault_injector)
+            fault_injector=fault_injector,
+            tracer=self.tracer)
         # hot-path perf subsystems, both paper-faithful OFF by default:
         # cache_capacity > 0 enables the (eid, pipeline-signature) result
         # cache; coalesce_window_ms > 0 enables cross-session remote
@@ -406,7 +413,8 @@ class VDMSAsyncEngine:
             self.batcher_backend = UDFBatcherBackend(
                 group_size=batcher_group_size,
                 max_wait_s=batcher_max_wait_ms / 1000.0,
-                tracker=self.cost_tracker)
+                tracker=self.cost_tracker,
+                tracer=self.tracer)
             if fault_injector is not None:
                 # offload backends consult the injector per group run
                 # (site "backend:<name>"); remote servers got theirs via
@@ -428,7 +436,8 @@ class VDMSAsyncEngine:
                               device_backend=self.device_backend,
                               cost_tracker=self.cost_tracker,
                               health=self.health,
-                              fallback_native=fallback == "native")
+                              fallback_native=fallback == "native",
+                              tracer=self.tracer)
         if dispatch == "native":
             self.router = StaticRouter("native")
         elif dispatch == "cost":
@@ -447,7 +456,8 @@ class VDMSAsyncEngine:
                 health=self.health)
         self.planner = QueryPlanner(self.meta, self.store,
                                     result_cache=self.result_cache,
-                                    router=self.router)
+                                    router=self.router,
+                                    tracer=self.tracer)
         if self.admission_ctl is not None:
             self.admission_ctl.bind(
                 loop=self.loop, pool=self.pool, launch=self._launch_now,
@@ -507,9 +517,17 @@ class VDMSAsyncEngine:
         engine was built with a tenant table."""
         if self._shut:
             raise RuntimeError("engine is shut down")
+        qid = str(next(self._qid))
+        with self.tracer.span("submit", qid=qid):
+            return self._submit(qid, query, on_entity, cache, priority,
+                                timeout_s, tenant)
+
+    def _submit(self, qid, query, on_entity, cache, priority, timeout_s,
+                tenant) -> QueryFuture:
+        """:meth:`submit`'s body, timed as the tracer's ``submit`` span:
+        parse, plan, phase-0 expansion, admission and the Queue_1 put."""
         cmds = parse_query(query)
         plan = self.planner.compile(cmds)
-        qid = str(next(self._qid))
         deadline = (time.monotonic() + timeout_s
                     if timeout_s is not None else None)
         session = QuerySession(qid, plan, self, on_entity=on_entity,
@@ -642,6 +660,7 @@ class VDMSAsyncEngine:
             self.result_cache.invalidate(ent.eid)
 
     def _entity_done(self, ent: Entity):
+        self.tracer.count("entities_done")
         with self._session_lock:
             session = self._sessions.get(ent.query_id)
         try:
@@ -694,20 +713,27 @@ class VDMSAsyncEngine:
         self.pool.scale_to(n)
 
     def utilization(self) -> dict:
+        """Engine-lifetime host counters: busy seconds of the native
+        workers (``thread2_busy_s``, summed) and of Thread_3, the native
+        pool's size, the remote pool's requests (``remote_dispatched``)
+        and the entity-operations its servers ran
+        (``remote_processed``), coalescing and retry counts, and
+        ``trace``: the spans, waits and counts of the engine's
+        :class:`~repro.core.trace.Tracer` (``{"spans": {name: {"n",
+        "s"}}, "waits": {name: {"n", "s"}}, "counts": {name: k}}``)."""
         return {
             "thread2_busy_s": self.loop.t2_meter.busy_seconds(),
             "thread3_busy_s": self.loop.t3_meter.busy_seconds(),
             "native_workers": self.num_native_workers,
             "remote_processed": sum(s.processed for s in self.pool.servers),
             "remote_dispatched": self.pool.dispatched,
-            "remote_transport_busy_s": sum(s.transport_busy_s
-                                           for s in self.pool.servers),
             "coalesced_batches": self.loop.coalesced_batches,
             "coalesced_entities": self.loop.coalesced_entities,
             "retried": self.pool.retried,
             "reissued": self.pool.reissued,
             "duplicates_dropped": self.pool.duplicates_dropped,
             "cancelled_dropped": self.pool.cancelled_dropped,
+            "trace": self.tracer.stats(),
         }
 
     def cache_stats(self) -> dict:

@@ -93,6 +93,7 @@ from typing import Any, Callable, Optional
 from repro.core.entity import ERD, Entity
 from repro.core.pipeline import run_native_chain, run_op
 from repro.core.remote import RemoteServerPool, Request
+from repro.core.trace import TimedQueue, Tracer, annotation
 from repro.distributed.fault import PermanentError
 
 _STOP = object()
@@ -108,20 +109,30 @@ class BusyMeter:
     retained window (the common case — benchmarks measure over recent
     marks); for a ``since`` older than the window it adds the full evicted
     aggregate, a documented over-approximation.
+
+    A meter with a ``name`` also opens the profiler annotation
+    ``vdms.<name>`` in :meth:`start` (with the ids it is given) and
+    closes it in :meth:`stop`; both run on the owner thread.
     """
 
-    def __init__(self, window: int = 4096):
+    def __init__(self, window: int = 4096, name: str | None = None):
         self.window = window
+        self.name = name
         self.intervals: collections.deque[tuple[float, float]] = \
             collections.deque()         # guarded-by: _lock
         self._t0: float | None = None   # owner thread only
+        self._ann = None                # owner thread only
         self._lock = threading.Lock()   # owner thread writes, readers poll
         self.total_busy_s = 0.0         # guarded-by: _lock
         self.total_intervals = 0        # guarded-by: _lock
         self._evicted_busy_s = 0.0      # guarded-by: _lock
         self._evicted_until = 0.0       # guarded-by: _lock
 
-    def start(self):
+    def start(self, **ids):
+        if self.name is not None:
+            self._ann = annotation(self.name, **ids)
+            if self._ann is not None:
+                self._ann.__enter__()
         self._t0 = time.monotonic()
 
     def stop(self):
@@ -129,6 +140,9 @@ class BusyMeter:
             return
         a, b = self._t0, time.monotonic()
         self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         with self._lock:
             self.intervals.append((a, b))
             self.total_busy_s += b - a
@@ -186,10 +200,14 @@ class FairQueue:
     round-robin rotation consults the counter to decide whether a lane
     stays in rotation, so a skewed counter starves later queries.  The
     counters double as the admission controller's Queue_1 depth signal.
+
+    Each entity is stamped when it is put and its wait is recorded as
+    the tracer's ``queue1`` wait when a worker takes it.
     """
 
-    def __init__(self, fair: bool = True):
+    def __init__(self, fair: bool = True, tracer: Tracer | None = None):
         self.fair = fair
+        self.tracer = tracer or Tracer()
         self._cv = threading.Condition()
         self._lanes: dict[str, collections.deque] = {}  # guarded-by: _cv
         self._rr: collections.deque[str] = \
@@ -207,18 +225,19 @@ class FairQueue:
         batch is fully queued, so a large fan-out cannot GIL-starve the
         submitting client while it is still enqueueing (keeps ``submit``
         O(ms) even for huge queries)."""
+        now = time.monotonic()
         with self._cv:
             for ent in ents:
                 qid = ent.query_id
                 self._counts[qid] = self._counts.get(qid, 0) + 1
                 if not self.fair:
-                    self._fifo.append(ent)
+                    self._fifo.append((ent, now))
                 else:
                     lane = self._lanes.get(qid)
                     if lane is None:
                         lane = self._lanes[qid] = collections.deque()
                         self._rr.append(qid)
-                    lane.append(ent)
+                    lane.append((ent, now))
             self._cv.notify_all()
 
     def get(self, timeout: float | None = None):
@@ -226,13 +245,13 @@ class FairQueue:
         with self._cv:
             while True:
                 if not self.fair and self._fifo:
-                    ent = self._fifo.popleft()
+                    ent, t_put = self._fifo.popleft()
                     self._dec_locked(ent.query_id)
-                    return ent
+                    break
                 if self.fair and self._rr:
                     qid = self._rr.popleft()
                     lane = self._lanes[qid]
-                    ent = lane.popleft()
+                    ent, t_put = lane.popleft()
                     # counter update atomic with the pop: rotation below
                     # trusts it, and discard() may run the instant the
                     # lock is released
@@ -241,11 +260,13 @@ class FairQueue:
                         self._rr.append(qid)   # rotate: next lane goes first
                     else:
                         del self._lanes[qid]
-                    return ent
+                    break
                 if self._closed:
                     return None
                 if not self._cv.wait(timeout):
                     return None
+        self.tracer.wait("queue1", time.monotonic() - t_put)
+        return ent
 
     def _dec_locked(self, qid: str) -> int:
         n = self._counts.get(qid, 0) - 1
@@ -261,7 +282,7 @@ class FairQueue:
         count."""
         with self._cv:
             if not self.fair:
-                kept = [e for e in self._fifo if e.query_id != query_id]
+                kept = [p for p in self._fifo if p[0].query_id != query_id]
                 n = len(self._fifo) - len(kept)
                 self._fifo = collections.deque(kept)
                 self._counts.pop(query_id, None)
@@ -309,8 +330,10 @@ class EventLoop:
                  cost_tracker=None,
                  health=None,
                  fallback_native: bool = False,
-                 clock=time.monotonic):
+                 clock=time.monotonic,
+                 tracer: Tracer | None = None):
         self.pool = pool
+        self.tracer = tracer or Tracer()
         self.erd = erd
         # fault-tolerance wiring (engine-provided, both default off):
         # ``health`` is the HealthRegistry fed per-attempt outcomes;
@@ -341,11 +364,14 @@ class EventLoop:
         self.num_native_workers = max(1, num_native_workers)
         self.on_entity_done = on_entity_done or (lambda e: None)
         self.is_cancelled = is_cancelled or (lambda qid: False)
-        self.queue1 = FairQueue(fair=fair_scheduling)  # native work
-        self.queue2: queue.Queue = queue.Queue()   # Thread_3 inbox: dispatch + responses
-        self._meters = [BusyMeter() for _ in range(self.num_native_workers)]
+        self.queue1 = FairQueue(fair=fair_scheduling,
+                                tracer=self.tracer)  # native work
+        # Thread_3 inbox: dispatches and replies
+        self.queue2: queue.Queue = TimedQueue(self.tracer, "queue2")
+        self._meters = [BusyMeter(name="native")
+                        for _ in range(self.num_native_workers)]
         self.t2_meter = MeterGroup(self._meters)
-        self.t3_meter = BusyMeter()
+        self.t3_meter = BusyMeter(name="thread3")
         self.straggler_check_s = straggler_check_s
         self.workers = [
             threading.Thread(target=self._native_worker, args=(m,), daemon=True,
@@ -378,7 +404,7 @@ class EventLoop:
                 return
             if self.is_cancelled(ent.query_id):
                 continue
-            meter.start()
+            meter.start(qid=ent.query_id, eid=ent.eid)
             try:
                 self._run_native(ent)
             except Exception as e:  # noqa: BLE001
@@ -532,8 +558,11 @@ class EventLoop:
             if msg is _STOP:
                 return
             if msg is not None:
-                self.t3_meter.start()
                 kind = msg[0]
+                if kind in ("dispatch", "batched", "device"):
+                    self.t3_meter.start(qid=msg[1].query_id, eid=msg[1].eid)
+                else:
+                    self.t3_meter.start()
                 if kind == "dispatch":
                     ent = msg[1]
                     backend = self._backend_for(ent)

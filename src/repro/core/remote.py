@@ -42,6 +42,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from repro.core.pipeline import Operation, run_op
+from repro.core.trace import TimedQueue, Tracer
 from repro.distributed.fault import (DeadlineExceeded, FaultInjector,
                                      HeartbeatMonitor, NoLiveServersError,
                                      PermanentError, TransientError)
@@ -85,17 +86,25 @@ def _batch_size(req: Request) -> int:
 
 
 class RemoteServer:
+    """One remote server: a worker thread serving its inbox, one request
+    at a time.  A request sleeps for its modelled network cost
+    (``TransportModel.cost_batch``, the tracer's ``remote_transport``
+    span), then runs its op on every entity it carries (the
+    ``remote_exec`` span); the time a request sat in the inbox is the
+    ``remote_inbox`` wait."""
+
     def __init__(self, sid: int, transport: TransportModel, *,
                  fault_injector: Optional[FaultInjector] = None,
                  beat: Optional[Callable[[int], None]] = None,
-                 beat_interval_s: float = 0.0):
+                 beat_interval_s: float = 0.0,
+                 tracer: Tracer | None = None):
         self.sid = sid
         self.transport = transport
-        self.inbox: queue.Queue = queue.Queue()
+        self.tracer = tracer or Tracer()
+        self.inbox: queue.Queue = TimedQueue(self.tracer, "remote_inbox")
         self.alive = True
         self.busy = False
         self.processed = 0
-        self.transport_busy_s = 0.0   # accumulated cost_batch time
         self._pending = 0             # queued + in-service ENTITIES
         self._pending_lock = threading.Lock()
         self._fi = fault_injector
@@ -219,14 +228,18 @@ class RemoteServer:
                 dt = self.transport.cost_batch(
                     [getattr(d, "nbytes", 0) for d in datas]) \
                     + self._fault_latency_s
-                self.transport_busy_s += dt
+                ids = ({"qid": ents[0].query_id, "n": len(ents)} if batched
+                       else {"qid": ents[0].query_id, "eid": ents[0].eid})
                 # network + remote-capacity cost (GIL-releasing)
-                time.sleep(dt)
-                results = [run_op(req.op, d) if self.transport.execute_ops
-                           else d for d in datas]
-                for r in results:
-                    if r is not None and hasattr(r, "block_until_ready"):
-                        r.block_until_ready()
+                with self.tracer.span("remote_transport", **ids):
+                    time.sleep(dt)
+                with self.tracer.span("remote_exec", **ids):
+                    results = [run_op(req.op, d)
+                               if self.transport.execute_ops else d
+                               for d in datas]
+                    for r in results:
+                        if r is not None and hasattr(r, "block_until_ready"):
+                            r.block_until_ready()
                 self.processed += len(results)
                 req.reply_to.put(("ok", req,
                                   results if batched else results[0]))
@@ -248,8 +261,10 @@ class RemoteServerPool:
                  retry_backoff_base_s: float = 0.0,
                  retry_backoff_max_s: float = 1.0,
                  heartbeat_timeout_s: float = 0.0,
-                 fault_injector: Optional[FaultInjector] = None):
+                 fault_injector: Optional[FaultInjector] = None,
+                 tracer: Tracer | None = None):
         self.transport = transport or TransportModel()
+        self.tracer = tracer or Tracer()
         self.policy = policy
         self.max_retries = max_retries
         self.straggler_factor = straggler_factor
@@ -296,7 +311,8 @@ class RemoteServerPool:
             interval = max(1e-3, self.heartbeat_timeout_s / 4.0)
         return RemoteServer(sid, self.transport,
                             fault_injector=self.fault_injector,
-                            beat=beat, beat_interval_s=interval)
+                            beat=beat, beat_interval_s=interval,
+                            tracer=self.tracer)
 
     def _beat(self, sid: int):
         self.monitor.beat(f"server-{sid}")

@@ -55,6 +55,8 @@ import threading
 import time
 from typing import Any, Callable, Optional
 
+from repro.core.trace import Tracer
+
 POLICIES = ("none", "queue", "shed")
 
 
@@ -99,7 +101,8 @@ class AdmissionController:
                  tenant_default_weight: float = 1.0,
                  cost_aware: bool = False,
                  cost_cap_s: float = 0.0,
-                 clock=time.monotonic):
+                 clock=time.monotonic,
+                 tracer=None):
         if policy not in ("queue", "shed"):
             raise ValueError(
                 f"admission policy must be 'queue' or 'shed' once "
@@ -163,14 +166,17 @@ class AdmissionController:
         self._reserved_cost_total = 0.0               # guarded-by: _lock
         self._reserved_cost_by_query: dict[str, float] = {}  # guarded-by: _lock
         self._clock = clock
+        # each entity's time in the pending lane is the tracer's
+        # admission wait (0 for an entity admitted at once)
+        self.tracer = tracer or Tracer()
         self._lock = threading.Lock()
         self._inflight = 0                            # guarded-by: _lock
         self._inflight_by_query: dict[str, int] = {}  # guarded-by: _lock
-        # pending lane: heap of (-priority, seq, entity); seq keeps FIFO
-        # order within a priority.  _pending_by_query is the liveness
-        # ledger — a heap entry whose query has no pending count is a
-        # tombstone left by drop_query and is skipped at pop time.
-        self._heap: list[tuple[int, int, Any]] = []   # guarded-by: _lock
+        # pending lane: heap of (-priority, seq, entity, parked-at); seq
+        # keeps FIFO order within a priority.  _pending_by_query is the
+        # liveness ledger — a heap entry whose query has no pending count
+        # is a tombstone left by drop_query and is skipped at pop time.
+        self._heap: list[tuple[int, int, Any, float]] = []  # guarded-by: _lock
         self._seq = itertools.count()
         self._pending_total = 0                       # guarded-by: _lock
         self._pending_by_query: dict[str, int] = {}   # guarded-by: _lock
@@ -553,6 +559,8 @@ class AdmissionController:
                 self.admitted += n
                 if self._v2():
                     self._charge_inflight_locked(qid, tenant, n * per)
+                for _ in ents:
+                    self.tracer.wait("admission", 0.0)
                 return [*ents, *self._drain_locked()]
             if reserved < n:
                 # the unreserved remainder must pass the normal check
@@ -564,8 +572,10 @@ class AdmissionController:
             # every entity enters the lane, then the drain pops in
             # global priority order — new work can never jump ahead of
             # equal-or-higher-priority work already waiting
+            now = time.monotonic()
             for e in ents:
-                heapq.heappush(self._heap, (-priority, next(self._seq), e))
+                heapq.heappush(self._heap,
+                               (-priority, next(self._seq), e, now))
             self._pending_total += n
             self._pending_by_query[qid] = \
                 self._pending_by_query.get(qid, 0) + n
@@ -597,8 +607,9 @@ class AdmissionController:
         (or a cheaper one) may still fit, and the blocked entry keeps
         its priority/FIFO position for the next drain."""
         out = []
-        skipped: list[tuple[int, int, Any]] = []
+        skipped: list[tuple[int, int, Any, float]] = []
         v2 = self._v2()
+        now = time.monotonic()
         while self._heap and self._inflight < self.max_inflight:
             item = heapq.heappop(self._heap)
             ent = item[2]
@@ -634,6 +645,7 @@ class AdmissionController:
                 else:
                     self._pending_cost_by_query[qid] = left
                 self._charge_inflight_locked(qid, t, c)
+            self.tracer.wait("admission", now - item[3])
             out.append(ent)
         for item in skipped:
             heapq.heappush(self._heap, item)

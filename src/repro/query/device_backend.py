@@ -252,7 +252,8 @@ class DeviceBackend(OffloadInboxMixin):
                  cost_model: DeviceCostModel | None = None,
                  calibrate: bool = True, clock=time.monotonic,
                  fuse_segments: bool = False,
-                 jit_cache_cap: int = 128):
+                 jit_cache_cap: int = 128,
+                 tracer=None):
         from repro.query.dispatch import LoadLedger, OpCostTracker
         import jax
         self.batch_size = max(1, batch_size)
@@ -268,7 +269,7 @@ class DeviceBackend(OffloadInboxMixin):
         # single device stream: the worker serializes device calls, so
         # the ledger drains at 1 work-second per wall second
         self.ledger = LoadLedger(lambda: 1.0, clock=clock)
-        self._init_inbox()
+        self._init_inbox(tracer)
         self._reply_to: Optional[queue.Queue] = None
         self._is_cancelled = lambda qid: False
         # bounded LRU of compiled programs: per-op signature keys on the
@@ -398,10 +399,14 @@ class DeviceBackend(OffloadInboxMixin):
             if first is OFFLOAD_STOP:
                 self._drain_after_stop()
                 return
-            group, stop = collect_microbatch(
-                self.inbox, first, size=self.batch_size,
-                max_wait_s=self.max_wait_s, clock=self._clock,
-                stop=OFFLOAD_STOP)
+            # the hold counts once per member of the group it gathers
+            with self.tracer.span("device_collect", qid=first.query_id,
+                                  eid=first.eid) as span:
+                group, stop = collect_microbatch(
+                    self.inbox, first, size=self.batch_size,
+                    max_wait_s=self.max_wait_s, clock=self._clock,
+                    stop=OFFLOAD_STOP)
+                span.weight = len(group)
             self._run_groups(group)
             if stop:
                 self._drain_after_stop()
@@ -527,30 +532,32 @@ class DeviceBackend(OffloadInboxMixin):
         settled by :meth:`_finalize_staged` after the next partition has
         been staged (double-buffering: h2d N+1 overlaps compute N)."""
         try:
-            self._maybe_fault()
-            arrs = [np.asarray(e.data) for e in live]
-            n = len(arrs)
-            if n == 1:
-                # singleton: no bucket, no padding waste
-                batch = arrs[0][None]
-                pad = 0
-            else:
-                batch = np.stack(arrs)
-                pad = self._bucket(n) - n
-                if pad:
-                    batch = np.concatenate(
-                        [batch, np.repeat(batch[-1:], pad, axis=0)])
-            self.stacked_rows += n
-            self.pad_rows += pad
-            import jax
-            on_dev = jax.device_put(batch, self.device)
-            self.h2d_bytes += batch.nbytes
-            fn = self._jit_lookup(skey,
-                                  lambda: self._build_segment_fn(seg))
-            ckey = (skey, batch.shape)
-            fresh = ckey not in self._compiled
-            t0 = self._clock()
-            out = fn(on_dev)
+            with self.tracer.span("device_stage", qid=live[0].query_id,
+                                  n=len(live)):
+                self._maybe_fault()
+                arrs = [np.asarray(e.data) for e in live]
+                n = len(arrs)
+                if n == 1:
+                    # singleton: no bucket, no padding waste
+                    batch = arrs[0][None]
+                    pad = 0
+                else:
+                    batch = np.stack(arrs)
+                    pad = self._bucket(n) - n
+                    if pad:
+                        batch = np.concatenate(
+                            [batch, np.repeat(batch[-1:], pad, axis=0)])
+                self.stacked_rows += n
+                self.pad_rows += pad
+                import jax
+                on_dev = jax.device_put(batch, self.device)
+                self.h2d_bytes += batch.nbytes
+                fn = self._jit_lookup(skey,
+                                      lambda: self._build_segment_fn(seg))
+                ckey = (skey, batch.shape)
+                fresh = ckey not in self._compiled
+                t0 = self._clock()
+                out = fn(on_dev)
             return _Staged(seg=seg, skey=skey, live=live, n=n, out=out,
                            t0=t0, fresh=fresh, ckey=ckey)
         except Exception as e:  # noqa: BLE001 — report, don't kill worker
@@ -562,8 +569,10 @@ class DeviceBackend(OffloadInboxMixin):
     def _finalize_staged(self, st: Optional[_Staged]):
         if st is None:
             return
+        ids = {"qid": st.live[0].query_id, "n": st.n}
         try:
-            st.out.block_until_ready()
+            with self.tracer.span("device_settle", **ids):
+                st.out.block_until_ready()
             exec_s = self._clock() - st.t0
             if st.fresh:
                 self._compiled.add(st.ckey)
@@ -572,9 +581,10 @@ class DeviceBackend(OffloadInboxMixin):
                 # amortization term, which only needs the magnitude
                 self.cost_model.observe_compile(exec_s)
             import jax
-            res = np.asarray(jax.device_get(st.out))
-            self.d2h_bytes += res.nbytes
-            results = [res[i] for i in range(st.n)]
+            with self.tracer.span("device_fetch", **ids):
+                res = np.asarray(jax.device_get(st.out))
+                self.d2h_bytes += res.nbytes
+                results = [res[i] for i in range(st.n)]
         except Exception as e:  # noqa: BLE001
             self.errors += 1
             for ent in st.live:
@@ -613,29 +623,31 @@ class DeviceBackend(OffloadInboxMixin):
     def _deliver(self, seg, skey, live, results, exec_s):
         """Shared tail of a fused/host partition: calibration, counters,
         one reply per entity advancing the whole segment."""
-        first_run = skey not in self._runs
-        if not first_run:
-            # attribute the partition wall evenly across the segment's
-            # ops (the same rough-but-calibrating split fuse_native
-            # uses); the FIRST run is skipped — compile-contaminated
-            per_op = exec_s / len(live) / len(seg)
-            out_bytes = getattr(results[0], "nbytes", None)
-            for k, op in enumerate(seg):
-                self.tracker.observe(
-                    op, per_op, kind="device",
-                    out_bytes=out_bytes if k == len(seg) - 1 else None)
-        self._runs[skey] = self._runs.get(skey, 0) + 1
-        for op in seg:
-            # per-op run counts drive estimate()'s compile amortization
-            sig = op_signature(op)
-            self._runs[sig] = self._runs.get(sig, 0) + 1
-        self.groups_run += 1
-        self.entities_run += len(live)
-        self.ops_run += len(live) * len(seg)
-        if len(seg) > 1:
-            self.fused_segments += 1
-        for ent, res in zip(live, results):
-            self._reply_to.put((DEVICE, ent, res, None, len(seg)))
+        with self.tracer.span("device_deliver", qid=live[0].query_id,
+                              n=len(live)):
+            first_run = skey not in self._runs
+            if not first_run:
+                # attribute the partition wall evenly across the segment's
+                # ops (the same rough-but-calibrating split fuse_native
+                # uses); the FIRST run is skipped — compile-contaminated
+                per_op = exec_s / len(live) / len(seg)
+                out_bytes = getattr(results[0], "nbytes", None)
+                for k, op in enumerate(seg):
+                    self.tracker.observe(
+                        op, per_op, kind="device",
+                        out_bytes=out_bytes if k == len(seg) - 1 else None)
+            self._runs[skey] = self._runs.get(skey, 0) + 1
+            for op in seg:
+                # per-op run counts drive estimate()'s compile amortization
+                sig = op_signature(op)
+                self._runs[sig] = self._runs.get(sig, 0) + 1
+            self.groups_run += 1
+            self.entities_run += len(live)
+            self.ops_run += len(live) * len(seg)
+            if len(seg) > 1:
+                self.fused_segments += 1
+            for ent, res in zip(live, results):
+                self._reply_to.put((DEVICE, ent, res, None, len(seg)))
 
     # ------------------------------------------------------ per-op path
     def _run_partition(self, op, ents):

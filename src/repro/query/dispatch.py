@@ -78,6 +78,7 @@ import time
 from typing import Optional
 
 from repro.core.result_cache import op_signature
+from repro.core.trace import TimedQueue, Tracer
 
 NATIVE = "native"
 REMOTE = "remote"
@@ -146,8 +147,10 @@ class OffloadInboxMixin:
     loops treat ``OFFLOAD_STOP`` as the pill, calling
     :meth:`_drain_after_stop` when they see it."""
 
-    def _init_inbox(self) -> None:
-        self.inbox: queue.Queue = queue.Queue()
+    def _init_inbox(self, tracer=None) -> None:
+        self.tracer = tracer or Tracer()
+        # each entity's wait here is the tracer's offload_inbox wait
+        self.inbox: queue.Queue = TimedQueue(self.tracer, "offload_inbox")
         self._thread: Optional[threading.Thread] = None
         self._closed = threading.Event()
         self._submit_gate = threading.Lock()

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.entity import Entity
 from repro.core.result_cache import ResultCache, prefix_signatures
+from repro.core.trace import Tracer
 from repro.query.language import Command
 from repro.query.metadata import MetadataStore
 from repro.storage.store import BlobStore
@@ -98,11 +99,12 @@ class QueryPlanner:
 
     def __init__(self, meta: MetadataStore, store: BlobStore,
                  result_cache: ResultCache | None = None,
-                 router=None):
+                 router=None, tracer: Tracer | None = None):
         self.meta = meta
         self.store = store
         self.result_cache = result_cache
         self.router = router      # BackendRouter | StaticRouter | None
+        self.tracer = tracer or Tracer()
 
     # ----------------------------------------------------------- compile
     def compile(self, cmds: list[Command]) -> QueryPlan:
@@ -157,7 +159,16 @@ class QueryPlanner:
         """Fan a command out into entities (ingesting first for Add).
         Records the matched-eid order on the plan for result assembly.
         ``use_cache=False`` (a ``submit(..., cache=False)`` query)
-        bypasses the result cache for both reads and writes."""
+        bypasses the result cache for both reads and writes.  Timed as
+        the tracer's ``expand`` span; the entities it returns count as
+        ``entities_planned``."""
+        with self.tracer.span("expand", qid=query_id):
+            ents = self._fan_out(cplan, query_id, use_cache)
+        self.tracer.count("entities_planned", len(ents))
+        return ents
+
+    def _fan_out(self, cplan: CommandPlan, query_id: str,
+                 use_cache: bool) -> list[Entity]:
         cmd = cplan.command
         if cmd.verb == "add":
             eids = [self.ingest(cmd.kind, cmd.data, cmd.properties,

@@ -189,14 +189,14 @@ class UDFBatcherBackend(OffloadInboxMixin):
     name = "batcher"
 
     def __init__(self, *, group_size: int = 8, max_wait_s: float = 0.002,
-                 tracker=None, clock=time.monotonic):
+                 tracker=None, clock=time.monotonic, tracer=None):
         from repro.query.dispatch import LoadLedger, OpCostTracker
         self.group_size = max(1, group_size)
         self.max_wait_s = max(0.0, max_wait_s)
         self.tracker = tracker or OpCostTracker()
         self._clock = clock
         self.ledger = LoadLedger(lambda: 1.0, clock=clock)
-        self._init_inbox()
+        self._init_inbox(tracer)
         self._reply_to: Optional[queue.Queue] = None
         self._is_cancelled = lambda qid: False
         self.groups_run = 0

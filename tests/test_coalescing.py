@@ -234,12 +234,15 @@ def test_batched_request_sleeps_cost_batch_not_cost_sum():
         pool.dispatch(ents, op, reply)
         tag, req, payload = reply.get(timeout=10)
         assert tag == "ok" and len(payload) == 4
-        server = pool.servers[0]
         per_payload_sum = sum(t.cost(e.data.nbytes) for e in ents)
         batch_cost = t.cost_batch([e.data.nbytes for e in ents])
-        assert abs(server.transport_busy_s - batch_cost) < 1e-9
-        # the amortization is real: one latency, not four
-        assert server.transport_busy_s < per_payload_sum - 0.1
+        # one remote_transport span for the batched request: it slept
+        # cost_batch (a sleep lasts at least its argument) ...
+        slept = pool.tracer.stats()["spans"]["remote_transport"]
+        assert slept["n"] == 1
+        assert slept["s"] >= batch_cost - 1e-6
+        # ... and the amortization is real: one latency, not four
+        assert slept["s"] < per_payload_sum - 0.1
     finally:
         pool.shutdown()
 
