@@ -1,23 +1,25 @@
-"""Operation pipeline representation + native-chain fusion.
+"""Operation pipeline representation + compiled op programs.
 
 Beyond-paper optimization (ARCHITECTURE.md, ``fuse_native``): VDMS-Async
 executes pipeline operations one at a time; here, maximal runs of native
 ops are jit-fused into a single compiled callable, cached per
 (chain-signature, input-shape).  One dispatch replaces N, and XLA fuses
-the elementwise stages.
+the elementwise stages.  The same cache holds the one-op programs the
+remote servers run (:func:`run_compiled`).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import json
+import threading
 from typing import Any
 
 import jax
 import numpy as np
 
 from repro.visual import facedetect
-from repro.visual.ops import NATIVE_OPS, apply_native_op
+from repro.visual.ops import NATIVE_OPS
 
 # compound vision UDFs shipped with the system (run locally when an op is
 # tagged native, or on a remote server / UDF process otherwise)
@@ -27,6 +29,9 @@ BUILTIN_UDFS = {
     "manipulation": facedetect.facedetect_manipulation,
     "activityrecognition": facedetect.activity_recognition,
 }
+# builtins that read a traced value on the host (``int(device_get(..))``)
+# and so run eagerly only
+UNTRACEABLE_UDFS = frozenset({"activityrecognition"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +82,13 @@ def parse_operations(op_list: list[dict]) -> list[Operation]:
     return out
 
 
+def _local_op(name: str):
+    """The function of a locally known op: the native table first, then
+    the builtin UDFs; None for any other name (a user UDF)."""
+    fn = NATIVE_OPS.get(name)
+    return fn if fn is not None else BUILTIN_UDFS.get(name)
+
+
 def run_op(op: Operation, img):
     """Execute one op locally (native table first, then builtin UDFs).
     Video entities (T,H,W,C) are processed frame-by-frame — ops stay
@@ -85,26 +97,68 @@ def run_op(op: Operation, img):
         import numpy as _np
         frames = [run_op(op, img[t]) for t in range(img.shape[0])]
         return _np.stack([_np.asarray(f) for f in frames])
-    if op.name in NATIVE_OPS:
-        return apply_native_op(op.name, img, op.kwargs)
-    if op.name in BUILTIN_UDFS:
-        return BUILTIN_UDFS[op.name](img, **op.kwargs)
-    from repro.core.udf import get_udf
-    return get_udf(op.name)(img, **op.kwargs)
+    fn = _local_op(op.name)
+    if fn is None:
+        from repro.core.udf import get_udf
+        fn = get_udf(op.name)
+    return fn(img, **op.kwargs)
 
 
-# ------------------------------------------------------------- fusion
+# ------------------------------------------------------ compiled programs
 @functools.lru_cache(maxsize=256)
 def _fused_chain(chain: tuple, shape: tuple, dtype_str: str):
-    """jit-compile a maximal native-op run as one callable."""
-    ops = [Operation(*c) for c in chain]
+    """jit-compile a run of locally known ops, ``((name, params), ...)``,
+    as one callable."""
+    fns = []
+    for name, params in chain:
+        fn = _local_op(name)
+        if fn is None:
+            raise KeyError(f"unknown local op {name!r}")
+        fns.append((fn, dict(params)))
 
     def chained(img):
-        for op in ops:
-            img = apply_native_op(op.name, img, op.kwargs)
+        for fn, kwargs in fns:
+            img = fn(img, **kwargs)
         return img
 
     return jax.jit(chained)
+
+
+# held only while the cache is looked up and a (lazy) jit wrapper built,
+# never across a trace or a dispatch: two threads that miss at once then
+# build one program, not two
+_build_lock = threading.Lock()
+
+
+def _run_chain(ops, img):
+    arr = jax.numpy.asarray(img)      # one upload for the whole program
+    key = tuple((o.name, o.params) for o in ops)
+    with _build_lock:
+        fn = _fused_chain(key, arr.shape, str(arr.dtype))
+    return fn(arr)
+
+
+def compilable(op: Operation, img) -> bool:
+    """Whether ``op`` on ``img`` runs as one compiled program: an image
+    entity (``ndim == 3``), an op of the native table or a traceable
+    builtin UDF, and hashable params.  User UDFs (arbitrary code),
+    untraceable builtins and video entities run eagerly (:func:`run_op`)."""
+    if getattr(img, "ndim", None) != 3:
+        return False
+    if _local_op(op.name) is None or op.name in UNTRACEABLE_UDFS:
+        return False
+    try:
+        hash(op.params)
+    except TypeError:
+        return False
+    return True
+
+
+def run_compiled(op: Operation, img):
+    """Run one op as its cached compiled program (one per op name,
+    params, input shape and dtype); the caller checks
+    :func:`compilable` first."""
+    return _run_chain((op,), img)
 
 
 def run_native_chain(ops: list[Operation], img, fuse: bool = True):
@@ -115,7 +169,4 @@ def run_native_chain(ops: list[Operation], img, fuse: bool = True):
         for op in ops:
             img = run_op(op, img)
         return img
-    arr = jax.numpy.asarray(img)
-    key = tuple((o.name, o.params, o.where, o.url, o.port) for o in ops)
-    fn = _fused_chain(key, arr.shape, str(arr.dtype))
-    return fn(arr)
+    return _run_chain(ops, img)

@@ -41,7 +41,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-from repro.core.pipeline import Operation, run_op
+from repro.core.pipeline import Operation, compilable, run_compiled, run_op
 from repro.core.trace import TimedQueue, Tracer
 from repro.distributed.fault import (DeadlineExceeded, FaultInjector,
                                      HeartbeatMonitor, NoLiveServersError,
@@ -91,7 +91,9 @@ class RemoteServer:
     (``TransportModel.cost_batch``, the tracer's ``remote_transport``
     span), then runs its op on every entity it carries (the
     ``remote_exec`` span); the time a request sat in the inbox is the
-    ``remote_inbox`` wait."""
+    ``remote_inbox`` wait.  An op runs as its cached compiled program
+    (``pipeline.run_compiled``, counted ``remote_compiled``) where
+    ``pipeline.compilable`` allows, else eagerly (``remote_eager``)."""
 
     def __init__(self, sid: int, transport: TransportModel, *,
                  fault_injector: Optional[FaultInjector] = None,
@@ -178,6 +180,16 @@ class RemoteServer:
                 f"injected error at remote server {self.sid}")))
         return True
 
+    def _execute(self, op: Operation, datas: list) -> list:
+        """``op`` on each entity's data: one cached compiled program per
+        (op, params, shape, dtype) where the input allows, else eager."""
+        paths = [compilable(op, d) for d in datas]
+        n = sum(paths)
+        self.tracer.count("remote_compiled", n)
+        self.tracer.count("remote_eager", len(paths) - n)
+        return [run_compiled(op, d) if c else run_op(op, d)
+                for c, d in zip(paths, datas)]
+
     def _run(self):
         self._fault_latency_s = 0.0
         while True:
@@ -234,9 +246,8 @@ class RemoteServer:
                 with self.tracer.span("remote_transport", **ids):
                     time.sleep(dt)
                 with self.tracer.span("remote_exec", **ids):
-                    results = [run_op(req.op, d)
-                               if self.transport.execute_ops else d
-                               for d in datas]
+                    results = (self._execute(req.op, datas)
+                               if self.transport.execute_ops else datas)
                     for r in results:
                         if r is not None and hasattr(r, "block_until_ready"):
                             r.block_until_ready()

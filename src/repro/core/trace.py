@@ -46,7 +46,8 @@ SPANS = ("submit", "expand", "device_collect", "device_stage",
          "device_settle", "device_fetch", "device_deliver",
          "remote_transport", "remote_exec")
 WAITS = ("admission", "queue1", "queue2", "offload_inbox", "remote_inbox")
-COUNTS = ("entities_planned", "entities_done", "compiles")
+COUNTS = ("entities_planned", "entities_done", "compiles",
+          "remote_compiled", "remote_eager")
 
 _Annotation = jax.profiler.TraceAnnotation
 _profiling = _Annotation.is_enabled

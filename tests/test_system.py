@@ -113,7 +113,10 @@ def test_multi_client_concurrent_queries():
 
 
 def test_failure_retry_and_elastic_scale():
-    eng = _mk_engine(num_remote_servers=3)
+    # a transport slow enough that the query is still in flight when
+    # server 0 dies 20 ms in (8 requests over 3 servers, ~10 ms each)
+    eng = _mk_engine(num_remote_servers=3, transport=TransportModel(
+        network_latency_s=0.001, service_time_s=0.01))
     try:
         _add_images(eng, 8)
 
@@ -121,10 +124,12 @@ def test_failure_retry_and_elastic_scale():
             time.sleep(0.02)
             eng.pool.kill_server(0)
 
-        threading.Thread(target=killer).start()
+        kill = threading.Thread(target=killer)
+        kill.start()
         res = eng.execute([{"FindImage": {
             "constraints": {"category": ["==", "lfw"]},
             "operations": PIPE}}], timeout=120)
+        kill.join()
         assert res["stats"]["failed"] == 0
         assert eng.pool.live_count() == 2
         eng.scale_remote(5)
