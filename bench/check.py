@@ -4,7 +4,9 @@ What a run's window produced is held to the guarantee the configuration
 states: every acknowledged query returns, exactly once, every entity its
 metadata predicate selects, with no entity failed, and the pixels of
 each returned entity are what the plain reference (``reference.py``,
-float32 on the host CPU) computes from the same input.
+float32 on the host CPU, or on the cell's first chip for a
+configuration's own op whose reference says ``ON_DEVICE``) computes from
+the same input.
 
 Numbers, each with its limit in ``bench/limits/<cell>.json``:
 
@@ -35,12 +37,16 @@ from bench import reference
 ELEMENT_TOL = 1e-4
 
 
-def output_numbers(checked, faces: np.ndarray, control_device=None) -> dict:
+def output_numbers(checked, faces: np.ndarray, control_device=None, *,
+                   bench_dir: str = reference.BENCH, op_data=None,
+                   chip=None) -> dict:
     """``max_abs_err`` and ``mismatch_share`` of ``checked``: a list of
     (operations, [(dataset index, output array)]).  With
     ``control_device`` the outputs are replaced by the control: the
     reference computed in bfloat16 on that device.  The reference itself
-    always runs in float32 on the host CPU."""
+    runs in float32 on the host CPU, a configuration's own op (its spec
+    and weights in ``op_data``) on ``chip`` where its reference file says
+    ``ON_DEVICE``; the control runs every op on ``control_device``."""
     import jax
     import jax.numpy as jnp
     cpu = jax.devices("cpu")[0]
@@ -55,16 +61,19 @@ def output_numbers(checked, faces: np.ndarray, control_device=None) -> dict:
         if not items:
             continue
         idx = np.array([i for i, _ in items])
-        want = reference.run(ops, faces[idx], cpu, jnp.float32)
+        kw = dict(bench_dir=bench_dir, op_data=op_data)
+        want = reference.run(ops, faces[idx], cpu, jnp.float32, chip=chip,
+                             **kw)
         if control_device is not None:
-            got = reference.run(ops, faces[idx], control_device, jnp.bfloat16)
+            got = reference.run(ops, faces[idx], control_device,
+                                jnp.bfloat16, **kw)
         else:
             got = [np.asarray(a) for _, a in items]
         for g, w in zip(got, want):
             g = np.asarray(g, np.float64)
             d = (np.abs(g - w) if g.shape == w.shape
                  else np.full(w.shape, np.inf))
-            if reference.is_discrete(ops):
+            if reference.is_discrete(ops, bench_dir):
                 diff += int(np.count_nonzero(~(d <= ELEMENT_TOL)))
                 total += d.size
             else:

@@ -7,7 +7,9 @@ For each seed, in this one process (which holds the chip): one run of
 the cell at its own load and sizes, whose compared numbers are the
 program's readings, and the control on the same compared entities: the
 plain reference computed in bfloat16 on the chip, the next precision
-below the float32 the configurations state, put in the program's place.
+below the float32 the configurations state, put in the program's place
+(a configuration's own op by its ``references/<op>.py``, given the same
+weights).
 One JSON line per seed on stdout; the benchmark's own runs never run the
 control.
 """

@@ -5,7 +5,7 @@ of the input and one write of the output, operations are those of the
 textbook algorithm.  A kernel's least time on a chip is the larger of
 operations over the peak rate and bytes over the memory bandwidth
 (``peaks.json``); a roofline share is that least time over the time the
-device was busy.
+devices were busy, summed over the devices.
 """
 from __future__ import annotations
 
@@ -79,10 +79,11 @@ def kernel_work(kernel: str, ops_list: list[dict],
 
 
 def roofline_share(readings, kernel: str):
-    """Percent: the least time of ``kernel``'s work for every entity the
-    device ran in the traced window, over the device's busy time there.
-    None where the cell's queries do not do that kernel's work, or the
-    trace or the chip's peaks are missing."""
+    """Percent: the least time on one chip of ``kernel``'s work for every
+    entity the devices ran in the traced window, over the busy time of
+    all the devices there: the reduced trace's ``busy_s`` is the mean
+    over its ``devices``.  None where the cell's queries do not do that
+    kernel's work, or the trace or the chip's peaks are missing."""
     if readings.trace is None or readings.peaks is None:
         return None
     works = [kernel_work(kernel, ops, readings.image_shape)
@@ -90,7 +91,7 @@ def roofline_share(readings, kernel: str):
     if not works or any(w is None for w in works) or len(set(works)) != 1:
         return None
     entities = readings.delta("device.entities_run", span="trace")
-    busy = readings.trace["busy_s"]
+    busy = readings.trace["busy_s"] * readings.trace["devices"]
     if not entities or busy <= 0:
         return None
     least, _ = least_time_s(*works[0], readings.peaks)

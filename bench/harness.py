@@ -6,16 +6,26 @@ this module finds each by its name (``configs/<config>.json``,
 metric's reader ``metrics/<metric>.py``), so a new cell, mix,
 configuration or metric is a new file and a new entry, never an edit.
 
-A run: generate the collection from the seed, build the engine the
-configuration describes, ingest, warm up every shape the mix uses,
-drive the closed-loop window from client threads in this process, read
-the device's memory peak, shut the engine down, compare what the window
+A configuration may own operations of its own (a model run as a query
+operation): ``"ops": {"<op>": {<sizes, source, assumed>}}``.  Each is
+two files found by the op's name: ``references/<op>.py``, the plain
+reference and the op's data drawn from the seed (see
+``bench/reference.py``), and ``ops/<op>.py``, whose
+``install(engine, spec, weights, devices)`` registers the op with the
+engine through the program's public UDF API, as a client would.
+
+A run: generate the collection and each owned op's weights from the
+seed, build the engine the configuration describes, install its owned
+ops, ingest, warm up every shape the mix uses, drive the closed-loop
+window from client threads in this process, read the device's memory
+peak, shut the engine down and drop it, compare what the window
 produced with the reference, and return the result line.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import importlib.util
 import json
 import math
@@ -44,6 +54,11 @@ class Cell:
     end_to_end: list
     per_layer: list          # metric entries measured in this cell
     bench_dir: str
+
+    @property
+    def ops(self) -> dict:
+        """The configuration's own operations: name -> spec."""
+        return self.config.get("ops", {})
 
 
 def _load(path: str) -> dict:
@@ -78,14 +93,19 @@ def load_cell(name: str, benchmark: str | None = None,
         bench_dir=bench_dir)
 
 
-def load_reader(bench_dir: str, metric: str):
-    """``read(readings)`` of ``metrics/<metric>.py``."""
-    path = os.path.join(bench_dir, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
+def load_module(bench_dir: str, sub: str, name: str):
+    """The module ``<bench_dir>/<sub>/<name>.py``, loaded from its file."""
+    path = os.path.join(bench_dir, sub, name + ".py")
+    spec = importlib.util.spec_from_file_location(f"bench_{sub}_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: str, metric: str):
+    """``read(readings)`` of ``metrics/<metric>.py``."""
+    return load_module(bench_dir, "metrics", metric).read
 
 
 def use_compile_cache() -> str:
@@ -112,6 +132,28 @@ def build_engine(config: dict):
     if "transport" in kw:
         kw["transport"] = TransportModel(**kw["transport"])
     return VDMSAsyncEngine(**kw)
+
+
+def own_op_data(cell: Cell, seed: int) -> dict:
+    """Each of the configuration's own ops: name -> {"spec", "weights"},
+    the weights drawn from the seed by ``references/<op>.py``'s
+    ``weights(spec, seed)`` (none where it has no such function)."""
+    from bench import reference
+    data = {}
+    for name, spec in cell.ops.items():
+        ref = reference.own_op(name, cell.bench_dir)
+        draw = getattr(ref, "weights", None)
+        data[name] = {"spec": spec,
+                      "weights": draw(spec, seed) if draw else {}}
+    return data
+
+
+def install_ops(eng, cell: Cell, op_data: dict, devices) -> None:
+    """``ops/<op>.py``'s ``install`` for each of the configuration's own
+    ops, given the same weights the reference will use."""
+    for name, d in op_data.items():
+        load_module(cell.bench_dir, "ops", name).install(
+            eng, d["spec"], d["weights"], devices)
 
 
 def _flatten(prefix: str, obj, out: dict):
@@ -180,13 +222,21 @@ class CompileCounter:
 
 
 # ------------------------------------------------------------- warm-up
-def warm_up(eng, cell: Cell, log, max_passes: int = 6) -> None:
+def warm_up(eng, cell: Cell, seed: int, log, max_passes: int = 6,
+            burst_s: float = 2.0) -> None:
     """Every query of the mix over each warm-up size (the batch buckets
     the configuration lists), pass after pass until a pass compiles
     nothing, or as many as the pass before it: what is left then is
     compiled anew on every call, which no warm-up clears.  Eager ops run
     on whichever worker thread picks an entity up, so one pass need not
-    reach every program the window will ask for."""
+    reach every program the window will ask for.
+
+    Each device worker compiles its own programs, and where the
+    configuration has several (``num_device_workers``) the router
+    decides which worker forms a group of which size.  So then the mix
+    itself is driven, by as many clients as the window has and on query
+    streams of its own, in bursts of ``burst_s`` until a burst compiles
+    nothing."""
     from bench.traffic import by_index
     sizes = cell.config["warmup_sizes"]
     counter = CompileCounter.get()
@@ -207,8 +257,24 @@ def warm_up(eng, cell: Cell, log, max_passes: int = 6) -> None:
             f"{after[1] - before[1]} persistent-cache hits")
         compiled = after[0] - before[0]
         if compiled in (0, last):
-            return
+            break
         last = compiled
+    if cell.config["engine"].get("num_device_workers", 1) == 1:
+        return
+    for rnd in range(max_passes):
+        before = counter.count()
+        recs = run_clients(eng, cell, seed, burst_s,
+                           first_client=(rnd + 1) * cell.traffic["clients"])
+        after = counter.count()
+        log(f"warm-up burst {rnd + 1}: {len(recs)} queries, "
+            f"{after[0] - before[0]} compiles, "
+            f"{after[1] - before[1]} persistent-cache hits")
+        bad = [r for r in recs if r.error or r.failed_entities]
+        if bad:
+            raise RuntimeError(f"warm-up burst: {len(bad)} queries failed, "
+                               f"first: {bad[0].error}")
+        if after[0] == before[0]:
+            return
 
 
 # ------------------------------------------------------------- window
@@ -257,26 +323,51 @@ def client_loop(eng, queries, end: float, deadline: float, records: list):
             rec.error = f"{type(e).__name__}: {e}"
 
 
+def start_clients(eng, cell: Cell, seed: int, end: float, deadline: float,
+                  first_client: int = 0):
+    """The mix's closed-loop clients, started, sending until ``end``:
+    client ``i`` sends query stream ``first_client + i`` of the seed.
+    Returns the threads and each client's list of records."""
+    from bench.traffic import stream
+    n = cell.traffic["clients"]
+    records = [[] for _ in range(n)]
+    threads = [threading.Thread(
+        target=client_loop, name=f"bench-client-{i}",
+        args=(eng, stream(cell.traffic, cell.config["collection"], seed,
+                          first_client + i),
+              end, deadline, records[i])) for i in range(n)]
+    for t in threads:
+        t.start()
+    return threads, records
+
+
+def run_clients(eng, cell: Cell, seed: int, seconds: float,
+                first_client: int) -> list:
+    """The mix's clients for ``seconds`` outside the window; returns
+    their records once every answer is in."""
+    end = time.monotonic() + seconds
+    deadline = end + RESULT_TIMEOUT_S
+    threads, records = start_clients(eng, cell, seed, end, deadline,
+                                     first_client)
+    for t in threads:
+        t.join(max(deadline - time.monotonic(), 0.0) + 5.0)
+    if any(t.is_alive() for t in threads):
+        raise RuntimeError("a client outside the window never returned")
+    return [r for rs in records for r in rs]
+
+
 def drive(eng, cell: Cell, seed: int, seconds: float, trace_dir: str | None,
           trace_seconds: float, log) -> dict:
     """The measured window; returns the records, the window's edges and
     the counter snapshots."""
-    from bench.traffic import stream
     counter = CompileCounter.get()
-    n = cell.traffic["clients"]
-    records = [[] for _ in range(n)]
     snaps = {}
     compiles0 = counter.count()
     snaps["start"] = snapshot(eng)
     t0 = time.monotonic()
     end = t0 + seconds
     deadline = end + RESULT_TIMEOUT_S
-    threads = [threading.Thread(
-        target=client_loop, name=f"bench-client-{i}",
-        args=(eng, stream(cell.traffic, cell.config["collection"], seed, i),
-              end, deadline, records[i])) for i in range(n)]
-    for t in threads:
-        t.start()
+    threads, records = start_clients(eng, cell, seed, end, deadline)
     if trace_dir is not None:
         import jax
         lead = max(0.0, (seconds - trace_seconds) / 2)
@@ -395,12 +486,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     props = faces.properties(seed, coll["faces"], coll["categories"],
                              coll["age_min"], coll["age_max"])
     phases.append(("faces", time.monotonic()))
+    op_data = own_op_data(cell, seed)
+    if op_data:
+        phases.append(("weights", time.monotonic()))
     eng = build_engine(cell.config)
     try:
+        install_ops(eng, cell, op_data, devices)
         eid_of = {i: eng.add_entity("image", images[i], props[i])
                   for i in range(len(images))}
         phases.append(("engine+ingest", time.monotonic()))
-        warm_up(eng, cell, log)
+        warm_up(eng, cell, seed, log)
         t_setup = time.monotonic()
         phases.append(("warm-up", t_setup))
         setup_s = t_setup - t_process
@@ -421,6 +516,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         snaps = window["snaps"]
     finally:
         eng.shutdown()
+    # the reference may need the device memory the engine held
+    del eng
+    gc.collect()
     recs = window["records"]
     numbers = query_checks(recs, eid_of, props)
     numbers["failed_queries"] += window["stuck_clients"]
@@ -428,7 +526,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     checked = [(r.query.operations,
                 [(idx_of[e], a) for e, a in r.outputs.items()])
                for r in recs if r.outputs is not None]
-    numbers.update(check.output_numbers(checked, images))
+    ref_kw = dict(bench_dir=cell.bench_dir, op_data=op_data, chip=devices[0])
+    numbers.update(check.output_numbers(checked, images, **ref_kw))
     correct, table = check.decide(numbers, cell.limits)
     e2e = end_to_end(window, seconds, setup_s)
     log(f"queries done in the window: {e2e['queries_done']}; "
@@ -464,7 +563,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                                    "idle_gaps": reduced["idle_gaps"]}
     if control:
         result["_control"] = check.output_numbers(
-            checked, images, control_device=devices[0])
+            checked, images, control_device=devices[0], **ref_kw)
     result["checks"] = table
     return result
 

@@ -1,7 +1,8 @@
 """remote_exec_ms (ms): the remote servers' real work per entity-op:
-``run_op`` and ``block_until_ready`` (the engine's ``remote_exec`` span,
-the modelled network sleep left out) over the entity-operations the
-servers processed, both over the window.  Read beside the profiler
+running the op, as a cached compiled program or eagerly, and
+``block_until_ready`` (the engine's ``remote_exec`` span, the modelled
+network sleep left out) over the entity-operations the servers
+processed, both over the window.  Read beside the profiler
 trace: silent where no device trace was reduced."""
 
 

@@ -13,15 +13,32 @@ device, jitted and vmapped.  The benchmark calls it with float32 on the
 host CPU to decide ``correct``; the control (``bench/control.py`` and the
 tests) calls it with bfloat16, the next precision below the float32 the
 configurations state.
+
+An op that is not one of these (``OPS``) is a configuration's own op,
+whose reference is the file ``references/<op>.py`` of the benchmark's
+directory.  Such a file imports nothing of ``repro`` and gives:
+
+- ``DISCRETE``: whether its output holds a discrete choice;
+- ``ON_DEVICE``: whether its reference runs on the cell's first chip
+  (a model too large for the host) rather than on the host CPU;
+- optionally ``weights(spec, seed) -> dict`` of arrays: the op's data,
+  drawn from the seed, which the program is given too;
+- ``reference(images, params, *, spec, weights, device, dtype) ->
+  np.ndarray``: the op over a batch of inputs, computed in ``dtype``
+  under ``jax.default_matmul_precision("highest")`` on ``device``, in
+  blocks it chooses so that it fits there.
 """
 from __future__ import annotations
 
 import functools
 import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+BENCH = os.path.dirname(os.path.abspath(__file__))
 
 # 5x7 glyphs of the caption font (one string per row, '1' = ink)
 GLYPHS = {
@@ -229,8 +246,17 @@ def op_steps(ops: list[dict]) -> list[tuple[str, dict]]:
     return steps
 
 
-def is_discrete(ops: list[dict]) -> bool:
-    return any(name in DISCRETE for name, _ in op_steps(ops))
+@functools.lru_cache(maxsize=None)
+def own_op(name: str, bench_dir: str = BENCH):
+    """The module ``references/<name>.py`` of a configuration's own op."""
+    from bench.harness import load_module
+    return load_module(bench_dir, "references", name)
+
+
+def is_discrete(ops: list[dict], bench_dir: str = BENCH) -> bool:
+    return any(name in DISCRETE if name in OPS
+               else own_op(name, bench_dir).DISCRETE
+               for name, _ in op_steps(ops))
 
 
 def reference_fn(ops: list[dict], dtype=jnp.float32):
@@ -251,10 +277,7 @@ def _compiled(key: str, dtype_name: str):
                                          jnp.dtype(dtype_name))))
 
 
-def run(ops: list[dict], images: np.ndarray, device, dtype=jnp.float32,
-        block: int = 32) -> np.ndarray:
-    """The reference over a batch (N, H, W, C) on ``device``, ``block``
-    images per call; float32 on the host."""
+def _run_builtin(ops, images, device, dtype, block):
     fn = _compiled(json.dumps(ops, sort_keys=True), jnp.dtype(dtype).name)
     out = []
     with jax.default_device(device):
@@ -262,3 +285,32 @@ def run(ops: list[dict], images: np.ndarray, device, dtype=jnp.float32,
             x = jax.device_put(images[lo:lo + block], device)
             out.append(np.asarray(fn(x).astype(jnp.float32)))
     return np.concatenate(out) if out else np.zeros((0,), np.float32)
+
+
+def run(ops: list[dict], images: np.ndarray, device, dtype=jnp.float32,
+        block: int = 32, *, bench_dir: str = BENCH, op_data=None,
+        chip=None) -> np.ndarray:
+    """The reference over a batch (N, H, W, C): each run of built-in ops
+    on ``device``, ``block`` images per call, and each configuration's
+    own op by its ``references/<op>.py``, given its spec and weights
+    from ``op_data`` (name -> {"spec", "weights"}), on ``chip`` where
+    the file says ``ON_DEVICE`` (``device`` where no chip is given)."""
+    steps = op_steps(ops)
+    x, i = images, 0
+    while i < len(ops):
+        name, params = steps[i]
+        if name in OPS:
+            j = i + 1
+            while j < len(ops) and steps[j][0] in OPS:
+                j += 1
+            x = _run_builtin(ops[i:j], x, device, dtype, block)
+            i = j
+            continue
+        ref = own_op(name, bench_dir)
+        d = op_data[name]
+        x = np.asarray(ref.reference(
+            x, params, spec=d["spec"], weights=d["weights"],
+            device=(chip or device) if ref.ON_DEVICE else device,
+            dtype=dtype))
+        i += 1
+    return x

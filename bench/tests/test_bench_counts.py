@@ -7,6 +7,7 @@ import pytest
 
 import bench_tiny  # noqa: F401 — puts the repository on sys.path
 from bench import counts
+from bench.harness import Readings, load_cell
 
 V5E = "TPU v5 lite"
 
@@ -64,3 +65,25 @@ def test_peaks_name_their_source_and_refuse_an_unknown_kind(tmp_path):
         counts.peaks("TPU v9 imaginary")
     with pytest.raises(KeyError):
         counts.peaks("cpu")
+
+
+def test_roofline_share_sums_busy_time_over_devices():
+    """One device running N entities and four running N/4 each, at the
+    same time per entity, read the same share: the trace's ``busy_s`` is
+    the mean over its devices."""
+    cell = load_cell("lfw_device.train_feed")
+    peaks = counts.peaks(V5E)
+    n, per_entity_s = 2000, 20e-6
+
+    def share(devices):
+        snaps = {k: {"device.entities_run": v} for k, v in (
+            ("trace_start", 0), ("trace_end", n))}
+        busy_each = n / devices * per_entity_s
+        trace = {"busy_s": busy_each, "window_s": 3.0, "devices": devices}
+        return counts.roofline_share(Readings(cell, snaps, trace, peaks),
+                                     "preprocess")
+    work = counts.kernel_work(
+        "preprocess", cell.traffic["queries"]["preprocess"], (250, 250, 3))
+    least, _ = counts.least_time_s(*work, peaks)
+    assert share(1) == pytest.approx(100 * least / per_entity_s)
+    assert share(4) == pytest.approx(share(1))

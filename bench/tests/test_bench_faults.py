@@ -45,6 +45,20 @@ def test_sound_runs_are_correct(tmp_path):
         assert res["attempted"] > 0 and res["failed"] == 0
 
 
+def test_several_device_workers_warm_up_and_are_correct(tmp_path):
+    """Four device workers (here all on the one CPU device): the warm-up
+    drives the mix in bursts until one compiles nothing, and the run is
+    correct."""
+    logs = []
+    res = bench_tiny.run(
+        tmp_path, "tiny_device.tiny_blur", log=logs.append,
+        engine_overrides={"tiny_device": {"num_device_workers": 4}})
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    bursts = [m for m in logs if m.startswith("warm-up burst")]
+    assert bursts and " 0 compiles" in bursts[-1]
+
+
 @pytest.mark.parametrize("fault", ["answer_altered", "half_batch"])
 def test_device_segment_faults_fail(tmp_path, device_paths, fault):
     device_paths({"answer_altered": _perturb_row0,
@@ -90,6 +104,31 @@ def test_entity_delivered_twice_fails(tmp_path, monkeypatch):
     res = bench_tiny.run(tmp_path, "tiny_device.tiny_blur")
     assert not res["correct"]
     assert res["checks"]["wrong_deliveries"]["value"] > 0
+
+
+def test_own_op_weights_perturbed_fail(tmp_path, monkeypatch):
+    """A configuration's own op installed with weights other than those
+    the reference is given."""
+    from bench import harness
+    load = harness.load_module
+
+    def perturbed(bench_dir, sub, name):
+        mod = load(bench_dir, sub, name)
+        if sub == "ops":
+            install = mod.install
+
+            def broken(engine, spec, weights, devices):
+                return install(engine, spec,
+                               {k: v * 1.001 for k, v in weights.items()},
+                               devices)
+            mod.install = broken
+        return mod
+    monkeypatch.setattr(harness, "load_module", perturbed)
+    res = bench_tiny.run(tmp_path, "tiny_owned.tiny_embed")
+    assert not res["correct"]
+    assert res["failed"] == 0
+    assert res["checks"]["max_abs_err"]["value"] > \
+        res["checks"]["max_abs_err"]["limit"]
 
 
 @pytest.mark.parametrize("cell", sorted(bench_tiny.CELLS))

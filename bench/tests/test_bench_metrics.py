@@ -36,7 +36,7 @@ def test_device_idle_share():
 def test_roofline_share(metric, cell, kernel):
     peaks = counts.peaks("TPU v5 lite")
     snaps = _snaps(**{"device.entities_run": (1000, 3000)})
-    trace = {"busy_s": 0.02, "window_s": 3.0}
+    trace = {"busy_s": 0.02, "window_s": 3.0, "devices": 1}
     work = counts.kernel_work(
         kernel, next(iter(load_cell(cell).traffic["queries"].values())),
         (250, 250, 3))

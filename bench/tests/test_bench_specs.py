@@ -2,6 +2,8 @@
 mix, limit and reader is found by name, and a new pair is a new file."""
 from __future__ import annotations
 
+import ast
+import glob
 import json
 import os
 import re
@@ -59,7 +61,7 @@ def test_configs_name_their_files():
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_loads_by_name(name):
     cell = harness.load_cell(name)
-    assert cell.chips == 1
+    assert cell.chips in (1, 4)
     reported = {m["name"] for m in cell.end_to_end}
     assert "setup_s" in reported and len(reported) >= 2
     assert cell.per_layer
@@ -68,7 +70,8 @@ def test_every_cell_loads_by_name(name):
     # a limit for every number this cell's comparison gives, and no other
     wants = {"failed_queries", "wrong_selections", "wrong_deliveries"}
     for ops in cell.traffic["queries"].values():
-        wants.add("mismatch_share" if reference.is_discrete(ops)
+        wants.add("mismatch_share"
+                  if reference.is_discrete(ops, cell.bench_dir)
                   else "max_abs_err")
     assert set(cell.limits) == wants
     assert all(cell.limits[k] == 0 for k in ("failed_queries",
@@ -94,3 +97,62 @@ def test_a_new_pair_is_new_files_only(tmp_path):
     with pytest.raises(KeyError):
         harness.load_cell("no_such.cell", benchmark=spec,
                           bench_dir=str(tmp_path / "bench"))
+    # a configuration that owns an op: its files are found by the op's name
+    owned = harness.load_cell("tiny_owned.tiny_embed", benchmark=spec,
+                              bench_dir=str(tmp_path / "bench"))
+    assert owned.ops == bench_tiny.OWNED_OPS
+    for op in owned.ops:
+        assert callable(harness.load_module(owned.bench_dir, "ops",
+                                            op).install)
+        ref = reference.own_op(op, owned.bench_dir)
+        assert callable(ref.reference) and ref.DISCRETE is False
+    for ops in owned.traffic["queries"].values():
+        assert not reference.is_discrete(ops, owned.bench_dir)
+
+
+def test_a_four_chip_cell_loads_by_name(tmp_path):
+    """A cell on four chips, with a device worker per chip, loads by name
+    like any other: the harness takes 1 or 4 chips."""
+    spec_path = bench_tiny.make(tmp_path)
+    bench = tmp_path / "bench"
+    cfg = json.load(open(bench / "configs" / "tiny_device.json"))
+    cfg["engine"]["num_device_workers"] = 4
+    (bench / "configs" / "tiny_device_x4.json").write_text(json.dumps(cfg))
+    (bench / "limits" / "tiny_device_x4.tiny_blur.json").write_text(
+        json.dumps(bench_tiny.LIMITS))
+    spec = json.load(open(spec_path))
+    spec["configs"].append({**spec["configs"][0], "name": "tiny_device_x4"})
+    spec["workloads"].append({"name": "tiny_device_x4.tiny_blur",
+                              "config": "tiny_device_x4",
+                              "traffic": "tiny_blur", "chips": 4,
+                              "why": "test"})
+    open(spec_path, "w").write(json.dumps(spec))
+    cell = harness.load_cell("tiny_device_x4.tiny_blur", benchmark=spec_path,
+                             bench_dir=str(bench))
+    assert cell.chips == 4
+    assert cell.config["engine"]["num_device_workers"] == 4
+    assert cell.limits == bench_tiny.LIMITS
+
+
+def _imported_modules(path):
+    names = set()
+    for node in ast.walk(ast.parse(open(path).read())):
+        if isinstance(node, ast.Import):
+            names.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            names.add(node.module)
+    return names
+
+
+def test_references_import_nothing_of_the_program():
+    """The plain references, the built-in ops' and every configuration's
+    own, import nothing of ``repro``."""
+    paths = ([os.path.join(harness.BENCH, "reference.py")]
+             + glob.glob(os.path.join(harness.BENCH, "references", "*.py"))
+             + glob.glob(os.path.join(bench_tiny.OWN_OP, "references",
+                                      "*.py")))
+    assert len(paths) >= 2
+    for path in paths:
+        mods = _imported_modules(path)
+        assert not any(m == "repro" or m.startswith("repro.")
+                       for m in mods), (path, mods)
