@@ -22,6 +22,7 @@ from repro.configs import (  # noqa: F401
     qwen3_moe_235b_a22b,
     granite_moe_1b_a400m,
     whisper_small,
+    kimi_vl_a3b,
 )
 
 ALL_ARCHS = [

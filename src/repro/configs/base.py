@@ -75,6 +75,33 @@ class ArchConfig:
     moe_capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.001
 
+    # DeepSeek-V3-style MoE under MLA (Kimi-VL): the first
+    # ``first_k_dense_replace`` layers are dense MLPs of ``d_ff``; the rest
+    # are MoE layers of ``num_experts`` routed experts of width ``moe_d_ff``
+    # plus ``num_shared_experts`` shared ones, merged into one MLP of width
+    # ``num_shared_experts * moe_d_ff`` that every token runs.  Experts are
+    # scored by sigmoid, selected by score + a correction bias, and weighed
+    # by the unbiased scores, renormalised over the top k
+    # (``norm_topk_prob``) and times ``routed_scaling_factor``; no token is
+    # dropped (repro.models.moe.apply_moe_share).
+    first_k_dense_replace: int = 0
+    moe_d_ff: int = 0
+    num_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: this chip holds experts [first_held_expert,
+    # first_held_expert + experts_held) of every MoE layer (0: all of them);
+    # it routes over all num_experts and computes only its own experts' part
+    experts_held: int = 0
+    first_held_expert: int = 0
+
+    # multi-head latent attention (MLA, DeepSeek-V2, without q-LoRA);
+    # kv_lora_rank > 0 turns it on
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # SSM (Mamba2)
     ssm_state: int = 0
     mamba_expand: int = 2
@@ -98,8 +125,13 @@ class ArchConfig:
     encoder_seq_len: int = 1_500  # whisper: 30s audio -> 1500 frames
 
     # modality frontend stubs
-    frontend: str = "none"  # none | vit_stub | audio_stub
+    frontend: str = "none"  # none | vit_stub | audio_stub | patch_merge
     num_patches: int = 0    # vlm: precomputed patch embeddings per image
+    # patch_merge: the RGB image cut into patch_size^2 patches, merge x
+    # merge neighbours concatenated and projected to d_model by one linear
+    # layer
+    patch_size: int = 0
+    patch_merge: int = 0
 
     # attention flavour for long-context applicability
     attention: str = "full"  # full | none (ssm) | hybrid
@@ -132,6 +164,20 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> tuple[int, ...]:
+        """The routed experts this chip holds (all where experts_held 0)."""
+        n = self.experts_held or self.num_experts
+        return tuple(range(self.first_held_expert, self.first_held_expert + n))
+
+    @property
+    def patch_features(self) -> int:
+        return self.patch_merge ** 2 * self.patch_size ** 2 * 3
 
     @property
     def mamba_d_inner(self) -> int:
@@ -182,6 +228,8 @@ class ArchConfig:
             # shared blocks (weight-tied): count once each
             total += self.num_shared_blocks * (attn + mlp)
             n_attn_layers = 0
+        elif self.is_mla:
+            return self._mla_param_count()
         elif self.is_moe:
             expert = 3 * d * self.d_ff
             router = d * self.num_experts
@@ -193,6 +241,25 @@ class ArchConfig:
             total += self.num_encoder_layers * (attn + mlp)
             total += self.num_layers * attn  # cross-attention
         total += self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        return int(total)
+
+    def _mla_param_count(self) -> int:
+        """MLA + DeepSeek-style MoE: every layer's attention and norms,
+        the dense layers' MLPs, and in each MoE layer the router, its
+        correction bias, the experts held here and the shared experts;
+        embedding and head (the image projector is not the LM's)."""
+        d, h, r = self.d_model, self.num_heads, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        attn = (d * h * qk + d * (r + self.qk_rope_head_dim) + r
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d + 2 * d)
+        dense = 3 * d * self.d_ff
+        moe = (d * self.num_experts + self.num_experts
+               + len(self.held_experts) * 3 * d * self.moe_d_ff
+               + 3 * d * self.num_shared_experts * self.moe_d_ff)
+        k = self.first_k_dense_replace
+        total = self.num_layers * attn + k * dense + (self.num_layers - k) * moe
+        total += self.vocab_size * d * (1 if self.tie_embeddings else 2) + d
         return int(total)
 
     def active_param_count(self) -> int:

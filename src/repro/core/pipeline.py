@@ -51,6 +51,14 @@ class Operation:
         return self.where == "native"
 
 
+def _frozen(value):
+    """A UDF's JSON list option (token ids, say) as a tuple, so that the
+    operation stays hashable: the device backend keys a group by it."""
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
 def make_op(name: str, params: dict | None = None, where: str = "native",
             url: str = "", port: int = 0) -> Operation:
     params = params or {}
@@ -70,7 +78,7 @@ def parse_operations(op_list: list[dict]) -> list[Operation]:
         e = dict(entry)
         typ = e.pop("type")
         if typ == "udf":
-            opts = dict(e.pop("options", {}))
+            opts = {k: _frozen(v) for k, v in e.pop("options", {}).items()}
             name = opts.pop("id")
             out.append(make_op(name, opts, where="udf", port=e.get("port", 0)))
         elif typ == "remote":

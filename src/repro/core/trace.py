@@ -44,10 +44,12 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 SPANS = ("submit", "expand", "device_collect", "device_stage",
          "device_settle", "device_fetch", "device_deliver",
-         "remote_transport", "remote_exec")
+         "remote_transport", "remote_exec",
+         "model_host", "model_prefill", "model_decode")
 WAITS = ("admission", "queue1", "queue2", "offload_inbox", "remote_inbox")
 COUNTS = ("entities_planned", "entities_done", "compiles",
-          "remote_compiled", "remote_eager")
+          "remote_compiled", "remote_eager",
+          "model_calls", "model_prefill_tokens", "model_decode_tokens")
 
 _Annotation = jax.profiler.TraceAnnotation
 _profiling = _Annotation.is_enabled

@@ -8,8 +8,11 @@ In-process transport models the paper's message queue: the UDF executor
 same decoupling as the paper's separate-process design, minus the wire.
 
 Model UDFs: ``register_model_udf`` wraps an assigned-architecture LM
-(via the serving layer) as a pipeline operation — the realistic
-"run ML inference inside the query" case the paper motivates.
+as a pipeline operation — the realistic "run ML inference inside the
+query" case the paper motivates.  A latent-attention model
+(Kimi-VL-A3B) scores candidate answers about each image as one device
+UDF over the micro-batch; the older presets label images through the
+serving layer.
 """
 from __future__ import annotations
 
@@ -91,26 +94,45 @@ def list_udfs() -> list[str]:
         return sorted(set(_REGISTRY) | set(BUILTIN_UDFS))
 
 
-def register_model_udf(name: str, arch: str = "qwen3-0.6b", *,
-                       steps: int = 4, reduced: bool = True,
-                       labels=("WALK", "RUN", "JUMP", "SIT")) -> None:
-    """Register an assigned-architecture LM as a classification UDF.
+def unregister_udf(name: str) -> None:
+    """Forget every variant registered under ``name`` (and so release
+    what they hold, a model's weights on the device among it)."""
+    with _LOCK:
+        for table in (_REGISTRY, _BATCHED, _DEVICE):
+            table.pop(name, None)
 
-    The image is hashed into a short token prompt; the LM decodes a few
+
+def register_model_udf(name: str, arch="qwen3-0.6b", *,
+                       steps: int = 4, reduced: bool = True,
+                       labels=("WALK", "RUN", "JUMP", "SIT"),
+                       weights: dict | None = None, tracer=None,
+                       device=None, buckets=(8, 16, 32)) -> None:
+    """Register an assigned-architecture LM as a UDF.
+
+    ``arch`` is a registered name or an :class:`ArchConfig`.  A
+    latent-attention model (``cfg.is_mla``) becomes a scoring op (see
+    :func:`_register_scoring_model`; ``weights`` is its recipe, ``tracer``
+    the engine's, ``device`` where it runs, ``buckets`` the group sizes
+    it compiles).  Any other preset becomes a classification UDF: the
+    image is hashed into a short token prompt; the LM decodes a few
     tokens and the argmax bucket picks a label stamped onto the image.
-    (The point is exercising real model inference inside the query
-    pipeline — prefill + decode through the serving layer.)
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_arch
+    from repro.configs.base import ArchConfig
     from repro.distributed.sharding import ShardingCtx
     from repro.models import get_model
     from repro.serving import greedy_generate
     from repro.visual.font import draw_text
 
-    cfg = get_arch(arch, reduced=reduced)
+    cfg = arch if isinstance(arch, ArchConfig) else get_arch(arch,
+                                                             reduced=reduced)
+    if cfg.is_mla:
+        _register_scoring_model(name, cfg, weights=weights, tracer=tracer,
+                                device=device, buckets=buckets)
+        return
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     sh = ShardingCtx(mesh=None)
@@ -194,3 +216,86 @@ def register_model_udf(name: str, arch: str = "qwen3-0.6b", *,
                     for img, t in zip(imgs, last)]
 
         register_device_udf(name, device_batched)
+
+
+def _register_scoring_model(name: str, cfg, *, weights, tracer, device,
+                            buckets) -> None:
+    """A latent-attention VLM (:mod:`repro.models.mla`) as an op that
+    scores candidate answers about each image.
+
+    Query options: ``prompt`` (P token ids) and ``candidates`` (C answers
+    of T ids each).  Per image: its merged patches and the prompt are
+    prefilled once; then the C answers are decoded teacher-forced, T - 1
+    steps, as C branches of that image's latent cache.  The result is a
+    float32 (C, T) array: the log-probability of each answer's t-th
+    token under the full-vocabulary log-softmax.
+
+    One device UDF runs a whole micro-batch: padded to the next of
+    ``buckets`` (so that at most that many programs of each kind
+    compile), one prefill call and T - 1 decode calls, no lock.  The
+    per-entity UDF is that over a list of one.  ``weights`` is a recipe
+    (:func:`repro.models.mla.draw`), drawn once here on ``device``; a
+    model registered before under ``name`` is dropped first.  Records
+    into ``tracer``: spans ``model_host`` (stack and put; fetch and
+    split), ``model_prefill`` and ``model_decode`` (each call's
+    dispatch), counts ``model_calls``, ``model_prefill_tokens`` and
+    ``model_decode_tokens`` (the group's real entities, not padding).
+    """
+    import functools
+    import gc
+
+    import jax
+    import numpy as np
+    from repro.core.trace import Tracer
+    from repro.models import mla
+
+    unregister_udf(name)
+    gc.collect()
+    if device is None:
+        device = jax.devices()[0]
+    tracer = tracer or Tracer()
+    if weights is None:
+        weights = mla.init_recipe(jax.random.PRNGKey(0), cfg)
+    params = mla.params_from_recipe(cfg, weights, device)
+    sizes = tuple(sorted(buckets))
+
+    prefill = jax.jit(functools.partial(mla.score_prefill, cfg=cfg),
+                      static_argnums=(3,))
+    decode = jax.jit(functools.partial(mla.score_decode, cfg=cfg),
+                     donate_argnums=(1,))
+
+    @functools.lru_cache(maxsize=16)
+    def ids_on_device(prompt, candidates):
+        return jax.device_put((np.asarray(prompt, np.int32),
+                               np.asarray(candidates, np.int32)), device)
+
+    def run(imgs, *, prompt, candidates, **_):
+        n = len(imgs)
+        size = next((b for b in sizes if b >= n), n)
+        steps = len(candidates[0]) - 1
+        with tracer.span("model_host", n=n):
+            batch = np.stack([np.asarray(im, np.float32) for im in imgs])
+            if size > n:
+                batch = np.concatenate(
+                    [batch, np.repeat(batch[-1:], size - n, axis=0)])
+            x = jax.device_put(batch, device)
+            ids = ids_on_device(tuple(prompt), tuple(map(tuple, candidates)))
+        with tracer.span("model_prefill", n=n):
+            lp, cache = prefill(params, x, ids, steps)
+        prefix = cache["c"].shape[2]
+        lps = [lp]
+        for t in range(steps):
+            with tracer.span("model_decode", n=n):
+                lp, cache = decode(params, cache, ids[1], np.int32(t))
+            lps.append(lp)
+        jax.block_until_ready(lps)
+        with tracer.span("model_host", n=n):
+            out = np.stack(jax.device_get(lps), axis=-1)[:n]
+            results = [out[i] for i in range(n)]
+        tracer.count("model_calls", 1 + steps)
+        tracer.count("model_prefill_tokens", n * prefix)
+        tracer.count("model_decode_tokens", n * len(candidates) * steps)
+        return results
+
+    register_udf(name, lambda img, **kw: run([img], **kw)[0])
+    register_device_udf(name, run)

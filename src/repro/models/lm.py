@@ -14,11 +14,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import ShardingCtx
-from repro.models import blocks, common, rwkv6
+from repro.models import blocks, common, mla, rwkv6
 from repro.models.mamba2 import conv_dim
 
 
 def family_kind(cfg: ArchConfig) -> str:
+    if cfg.is_mla:
+        return "mla"  # MLA + dense-first MoE (repro.models.mla)
     if cfg.family == "hybrid":
         return "hybrid"
     if cfg.family == "ssm":
@@ -40,8 +42,10 @@ def _prepend_axis(tree, name="layers"):
 # init
 # ======================================================================
 def init_lm(key: jax.Array, cfg: ArchConfig, dtype=jnp.float32) -> dict:
-    kg = common.KeyGen(key)
     kind = family_kind(cfg)
+    if kind == "mla":   # always bfloat16, drawn from a recipe
+        return mla.params_from_recipe(cfg, mla.init_recipe(key, cfg))
+    kg = common.KeyGen(key)
     p: dict[str, Any] = {
         "embed": common.normal(kg(), (cfg.padded_vocab, cfg.d_model), dtype, std=0.02),
         "final_norm": common.ones((cfg.d_model,), dtype),
@@ -71,6 +75,10 @@ def init_lm(key: jax.Array, cfg: ArchConfig, dtype=jnp.float32) -> dict:
 
 def lm_axes(cfg: ArchConfig) -> dict:
     kind = family_kind(cfg)
+    if kind == "mla":   # one chip: every tensor replicated
+        recipe = mla.init_recipe(jax.random.PRNGKey(0), cfg)
+        shapes = jax.eval_shape(lambda: mla.params_from_recipe(cfg, recipe))
+        return jax.tree.map(lambda a: (None,) * a.ndim, shapes)
     ax: dict[str, Any] = {"embed": ("vocab", "embed"), "final_norm": (None,)}
     if not cfg.tie_embeddings:
         ax["lm_head"] = ("embed", "vocab")
@@ -100,6 +108,11 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.float32) -> 
     kind = family_kind(cfg)
     hd = cfg.resolved_head_dim
     L = cfg.num_layers
+    if kind == "mla":   # the latent and the shared rope key per token
+        return {"c": jnp.zeros((L, batch, max_seq, cfg.kv_lora_rank),
+                               jnp.bfloat16),
+                "k_pe": jnp.zeros((L, batch, max_seq, cfg.qk_rope_head_dim),
+                                  jnp.bfloat16)}
     if kind == "tblock":
         kv = (L, batch, max_seq, cfg.num_kv_heads, hd)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
@@ -124,6 +137,9 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.float32) -> 
 def cache_axes(cfg: ArchConfig) -> dict:
     kind = family_kind(cfg)
     kv_ax = ("layers", "batch", "cache_seq", "cache_heads", None)
+    if kind == "mla":
+        return {"c": ("layers", "batch", "cache_seq", None),
+                "k_pe": ("layers", "batch", "cache_seq", None)}
     if kind == "tblock":
         return {"k": kv_ax, "v": kv_ax}
     if kind == "rwkv":
@@ -174,6 +190,10 @@ def forward(params, tokens, cfg: ArchConfig, sh: ShardingCtx,
             *, extra_embeds=None, remat: bool = False) -> tuple[jax.Array, jax.Array]:
     """Returns (logits (B,S,Vp), moe_aux)."""
     kind = family_kind(cfg)
+    if kind == "mla":
+        logits = mla.forward(params, mla.embed(params, tokens, extra_embeds),
+                             cfg)
+        return logits, jnp.zeros((), jnp.float32)
     h = embed_tokens(params, tokens, cfg, sh, extra_embeds)
     if kind == "rwkv":
         h = common.layer_norm(h, params["ln0_s"], params["ln0_b"], cfg.norm_eps)
@@ -228,8 +248,13 @@ def forward(params, tokens, cfg: ArchConfig, sh: ShardingCtx,
 # ======================================================================
 def prefill(params, tokens, cfg: ArchConfig, sh: ShardingCtx, max_cache: int,
             *, extra_embeds=None, cache_dtype=None) -> tuple[jax.Array, dict]:
-    """Returns (last-position logits (B,Vp), cache)."""
+    """Returns (last-position logits (B,Vp), cache).  For MLA the cache
+    holds the prompt's positions only (``max_cache`` is not used): its
+    decode continues in branches (:func:`repro.models.mla.branch_cache`)."""
     kind = family_kind(cfg)
+    if kind == "mla":
+        return mla.prefill(params, mla.embed(params, tokens, extra_embeds),
+                           cfg)
     h = embed_tokens(params, tokens, cfg, sh, extra_embeds)
     if kind == "rwkv":
         h = common.layer_norm(h, params["ln0_s"], params["ln0_b"], cfg.norm_eps)
@@ -297,8 +322,11 @@ def prefill(params, tokens, cfg: ArchConfig, sh: ShardingCtx, max_cache: int,
 def decode_step(params, tokens, cache, cache_index, cfg: ArchConfig,
                 sh: ShardingCtx) -> tuple[jax.Array, dict]:
     """tokens (B,1) int32; cache_index scalar int32 (valid length so far).
-    Returns (logits (B,Vp), new cache)."""
+    Returns (logits (B,Vp), new cache).  For MLA: tokens (B, C), one per
+    branch, ``cache_index`` the branch slot, logits (B, C, Vp)."""
     kind = family_kind(cfg)
+    if kind == "mla":
+        return mla.decode_step(params, tokens, cache, cache_index, cfg)
     h = embed_tokens(params, tokens, cfg, sh)
     if cfg.pos_scheme == "sinusoidal":
         # embed_tokens added position 0; replace with cache_index position

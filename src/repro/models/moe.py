@@ -1,4 +1,6 @@
-"""Mixture-of-Experts FFN (top-k routing, sort-based capacity dispatch).
+"""Mixture-of-Experts FFN (top-k routing, sort-based capacity dispatch),
+and the dropless expert-share layer of DeepSeek-V3-style models
+(``apply_moe_share``).
 
 TPU-native formulation: instead of the GShard one-hot dispatch einsum
 (whose one-hot matmul FLOPs would dwarf the expert FFN at 128 experts)
@@ -218,3 +220,65 @@ def apply_moe(p: dict, x: jax.Array, *, cfg: ArchConfig, sh: ShardingCtx,
     contrib = contrib * (flat_w[order] * keep.astype(x.dtype))[:, None]
     y = jnp.zeros((T, D), x.dtype).at[token_of].add(contrib)
     return y.reshape(B, S, D), aux
+
+
+# ------------------------------------------------- expert share (MLA/Kimi)
+def route_sigmoid_noaux_tc(x, router, bias, *, k: int, norm: bool,
+                           scale: float):
+    """DeepSeek-V3's ``noaux_tc`` router with one group, in float32:
+    scores ``sigmoid(x W)``; the top ``k`` of ``scores + bias`` chosen;
+    each chosen expert weighed by its unbiased score, renormalised over
+    the ``k`` (``norm``) and times ``scale``.  x (T, D) -> (weights (T, k)
+    float32, experts (T, k) int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, experts
+
+
+def apply_moe_share(p: dict, x: jax.Array, *, cfg: ArchConfig) -> jax.Array:
+    """One MoE layer's output on this chip: the held experts' part of the
+    routed sum plus the shared experts, for x (T, D) float32 -> (T, D)
+    float32.  Routing is over all ``cfg.num_experts``; a token's
+    assignments to experts held elsewhere add nothing here.  No token is
+    dropped: the held assignments are sorted by expert and run as one
+    grouped (ragged) matmul per projection, whose groups are exactly as
+    long as the tokens routed to each expert.
+
+    ``p``: ``router`` (D, E), ``bias`` (E,), the held experts' ``w_gate``,
+    ``w_up`` (E_h, D, F), ``w_down`` (E_h, F, D), and the shared experts
+    merged into one MLP, ``s_gate``, ``s_up`` (D, F_s), ``s_down``."""
+    T, _ = x.shape
+    K = cfg.num_experts_per_tok
+    n_held = p["w_gate"].shape[0]
+    w, experts = route_sigmoid_noaux_tc(
+        x, p["router"], p["bias"], k=K, norm=cfg.norm_topk_prob,
+        scale=cfg.routed_scaling_factor)
+    local = experts - cfg.first_held_expert
+    held = (local >= 0) & (local < n_held)
+    slot = jnp.where(held, local, n_held).reshape(-1)        # n_held: elsewhere
+    order = jnp.argsort(slot, stable=True)
+    token_of = order // K
+    sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
+    dt = p["w_gate"].dtype
+    xb = x.astype(dt)[token_of]
+
+    def grouped(lhs, rhs):
+        return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                  preferred_element_type=jnp.float32)
+    h = jax.nn.silu(grouped(xb, p["w_gate"])) * grouped(xb, p["w_up"])
+    y = grouped(h.astype(dt), p["w_down"])
+    # rows past the held groups are not computed: keep them out
+    mine = (slot[order] < n_held)[:, None]
+    y = jnp.where(mine, y * w.reshape(-1)[order][:, None], 0.0)
+    routed = jnp.zeros((T, x.shape[1]), jnp.float32).at[token_of].add(y)
+    xs = x.astype(dt)
+    shared = jax.nn.silu(jnp.dot(xs, p["s_gate"],
+                                 preferred_element_type=jnp.float32)) \
+        * jnp.dot(xs, p["s_up"], preferred_element_type=jnp.float32)
+    return routed + jnp.dot(shared.astype(dt), p["s_down"],
+                            preferred_element_type=jnp.float32)

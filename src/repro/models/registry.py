@@ -47,9 +47,15 @@ def _lm_api(cfg: ArchConfig) -> ModelAPI:
     def init(key, dtype=jnp.float32):
         return lm.init_lm(key, cfg, dtype)
 
+    def extra(params, batch):
+        if cfg.frontend == "patch_merge" and "images" in batch:
+            from repro.models import mla
+            return mla.image_embeds(params, batch["images"], cfg)
+        return batch.get("patch_embeds")
+
     def forward(params, batch, sh: ShardingCtx, remat=False):
         return lm.forward(params, batch["tokens"], cfg, sh,
-                          extra_embeds=batch.get("patch_embeds"), remat=remat)
+                          extra_embeds=extra(params, batch), remat=remat)
 
     def loss(params, batch, sh: ShardingCtx, remat=True):
         logits, aux = forward(params, batch, sh, remat=remat)
@@ -64,7 +70,7 @@ def _lm_api(cfg: ArchConfig) -> ModelAPI:
 
     def prefill(params, batch, sh: ShardingCtx, max_cache: int, cache_dtype=None):
         return lm.prefill(params, batch["tokens"], cfg, sh, max_cache,
-                          extra_embeds=batch.get("patch_embeds"),
+                          extra_embeds=extra(params, batch),
                           cache_dtype=cache_dtype)
 
     def decode_step(params, tokens, cache, cache_index, sh: ShardingCtx):

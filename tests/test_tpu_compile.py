@@ -95,3 +95,31 @@ def test_device_segment_program_compiles_with_kernels(faces):
     assert calls == 2
     out = jax.eval_shape(program, np.zeros(FACES, np.float32))
     assert out.shape == (8, 224, 224, 3)
+
+
+def test_expert_share_layer_compiles_at_published_widths(topo):
+    """One Kimi-VL MoE layer on this chip (8 of 64 experts, 2 shared) over
+    a prefill group of 32 x 105 tokens: the dropless grouped matmul
+    lowers to the TPU's ragged-dot kernel, and the layer fits."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from repro.configs.kimi_vl_a3b import FULL
+    from repro.models import moe
+    cfg = FULL.replace(experts_held=8)
+    one = SingleDeviceSharding(topo.devices[0])
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    fs = cfg.num_shared_experts * f
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    p = {"router": arg(d, e), "bias": arg(e), "w_gate": arg(8, d, f),
+         "w_up": arg(8, d, f), "w_down": arg(8, f, d), "s_gate": arg(d, fs),
+         "s_up": arg(d, fs), "s_down": arg(fs, d)}
+    x = arg(32 * 105, d, dtype=jnp.float32)
+    compiled = jax.jit(functools.partial(moe.apply_moe_share, cfg=cfg)) \
+        .lower(p, x).compile()
+    assert "ragged-dot" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -226,6 +226,50 @@ def test_device_engine_counts_match_its_own_counters():
     assert _delta(a, b, "spans", "remote_exec") == 0
 
 
+def test_model_op_spans_and_counts_for_one_micro_batch():
+    """A latent-attention model op (reduced Kimi-VL) run by the device
+    backend: one micro-batch of three faces is one prefill call and
+    ``steps`` decode calls, with the real faces' tokens counted."""
+    from repro.configs import get_arch
+    from repro.core.udf import register_model_udf, unregister_udf
+    cfg = get_arch("kimi-vl-a3b-instruct", reduced=True)
+    prompt, answers = (3, 4, 5), ((6, 7, 8), (9, 10, 11))
+    op = [{"type": "udf", "port": 0, "options": {
+        "id": "kimi_trace", "prompt": list(prompt),
+        "candidates": [list(a) for a in answers]}}]
+    eng = VDMSAsyncEngine(
+        num_remote_servers=1, transport=FAST, dispatch="cost",
+        device_backend="cpu", device_batch_size=3, device_max_wait_ms=500.0,
+        cost_overrides={"kimi_trace": ON_DEVICE["resize"]})
+    try:
+        register_model_udf("kimi_trace", cfg, tracer=eng.tracer,
+                           buckets=(4,))
+        _add_images(eng, 3, size=20)
+        eng.execute(_find(op), timeout=120)          # compiles
+        u0, d0 = eng.utilization(), eng.dispatch_stats()["device"]
+        res = eng.execute(_find(op), timeout=120)
+        u1, d1 = eng.utilization(), eng.dispatch_stats()["device"]
+    finally:
+        eng.shutdown()
+        unregister_udf("kimi_trace")
+    assert len(res["entities"]) == 3 and res["stats"]["failed"] == 0
+    assert all(np.asarray(a).shape == (2, 3)
+               for a in res["entities"].values())
+    assert d1["groups_run"] - d0["groups_run"] == 1
+    a, b = u0["trace"], u1["trace"]
+    steps = len(answers[0]) - 1
+    prefix = 3 * 3 + len(prompt)        # 3x3 merged 8x8 patches, the prompt
+    assert _delta(a, b, "counts", "model_calls") == 1 + steps
+    assert _delta(a, b, "counts", "model_prefill_tokens") == 3 * prefix
+    assert _delta(a, b, "counts", "model_decode_tokens") == \
+        3 * len(answers) * steps
+    assert _delta(a, b, "spans", "model_prefill") == 1
+    assert _delta(a, b, "spans", "model_decode") == steps
+    assert _delta(a, b, "spans", "model_host") == 2   # stage, then fetch
+    for span in ("model_host", "model_prefill", "model_decode"):
+        assert _delta(a, b, "spans", span, "s") > 0
+
+
 def test_utilization_has_no_modelled_transport_counter():
     eng = VDMSAsyncEngine()
     try:
