@@ -49,7 +49,8 @@ SPANS = ("submit", "expand", "device_collect", "device_stage",
 WAITS = ("admission", "queue1", "queue2", "offload_inbox", "remote_inbox")
 COUNTS = ("entities_planned", "entities_done", "compiles",
           "remote_compiled", "remote_eager",
-          "model_calls", "model_prefill_tokens", "model_decode_tokens")
+          "model_calls", "model_prefill_tokens", "model_decode_tokens",
+          "model_moe_rows", "model_moe_assignments")
 
 _Annotation = jax.profiler.TraceAnnotation
 _profiling = _Annotation.is_enabled

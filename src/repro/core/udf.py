@@ -239,7 +239,11 @@ def _register_scoring_model(name: str, cfg, *, weights, tracer, device,
     into ``tracer``: spans ``model_host`` (stack and put; fetch and
     split), ``model_prefill`` and ``model_decode`` (each call's
     dispatch), counts ``model_calls``, ``model_prefill_tokens`` and
-    ``model_decode_tokens`` (the group's real entities, not padding).
+    ``model_decode_tokens`` (the group's real entities, not padding),
+    ``model_moe_rows`` (the rows the MoE layers' grouped matmuls ran,
+    as the calls report them) and ``model_moe_assignments`` (tokens x
+    ``num_experts_per_tok`` x MoE layers of the calls, padding included,
+    as the rows are).
     """
     import functools
     import gc
@@ -258,6 +262,8 @@ def _register_scoring_model(name: str, cfg, *, weights, tracer, device,
         weights = mla.init_recipe(jax.random.PRNGKey(0), cfg)
     params = mla.params_from_recipe(cfg, weights, device)
     sizes = tuple(sorted(buckets))
+    assigned_per_token = cfg.num_experts_per_tok * (
+        cfg.num_layers - cfg.first_k_dense_replace)
 
     prefill = jax.jit(functools.partial(mla.score_prefill, cfg=cfg),
                       static_argnums=(3,))
@@ -281,20 +287,25 @@ def _register_scoring_model(name: str, cfg, *, weights, tracer, device,
             x = jax.device_put(batch, device)
             ids = ids_on_device(tuple(prompt), tuple(map(tuple, candidates)))
         with tracer.span("model_prefill", n=n):
-            lp, cache = prefill(params, x, ids, steps)
+            lp, cache, rows = prefill(params, x, ids, steps)
         prefix = cache["c"].shape[2]
-        lps = [lp]
+        lps, moe_rows = [lp], [rows]
         for t in range(steps):
             with tracer.span("model_decode", n=n):
-                lp, cache = decode(params, cache, ids[1], np.int32(t))
+                lp, cache, rows = decode(params, cache, ids[1], np.int32(t))
             lps.append(lp)
+            moe_rows.append(rows)
         jax.block_until_ready(lps)
         with tracer.span("model_host", n=n):
-            out = np.stack(jax.device_get(lps), axis=-1)[:n]
+            lps, moe_rows = jax.device_get((lps, moe_rows))
+            out = np.stack(lps, axis=-1)[:n]
             results = [out[i] for i in range(n)]
         tracer.count("model_calls", 1 + steps)
         tracer.count("model_prefill_tokens", n * prefix)
         tracer.count("model_decode_tokens", n * len(candidates) * steps)
+        tracer.count("model_moe_rows", int(sum(moe_rows)))
+        tracer.count("model_moe_assignments", assigned_per_token * size
+                     * (prefix + len(candidates) * steps))
         return results
 
     register_udf(name, lambda img, **kw: run([img], **kw)[0])

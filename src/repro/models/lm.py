@@ -253,8 +253,9 @@ def prefill(params, tokens, cfg: ArchConfig, sh: ShardingCtx, max_cache: int,
     decode continues in branches (:func:`repro.models.mla.branch_cache`)."""
     kind = family_kind(cfg)
     if kind == "mla":
-        return mla.prefill(params, mla.embed(params, tokens, extra_embeds),
-                           cfg)
+        logits, cache, _ = mla.prefill(
+            params, mla.embed(params, tokens, extra_embeds), cfg)
+        return logits, cache
     h = embed_tokens(params, tokens, cfg, sh, extra_embeds)
     if kind == "rwkv":
         h = common.layer_norm(h, params["ln0_s"], params["ln0_b"], cfg.norm_eps)
@@ -326,7 +327,9 @@ def decode_step(params, tokens, cache, cache_index, cfg: ArchConfig,
     branch, ``cache_index`` the branch slot, logits (B, C, Vp)."""
     kind = family_kind(cfg)
     if kind == "mla":
-        return mla.decode_step(params, tokens, cache, cache_index, cfg)
+        logits, cache, _ = mla.decode_step(params, tokens, cache,
+                                           cache_index, cfg)
+        return logits, cache
     h = embed_tokens(params, tokens, cfg, sh)
     if cfg.pos_scheme == "sinusoidal":
         # embed_tokens added position 0; replace with cache_index position

@@ -300,28 +300,33 @@ def attn_decode(p, xn, cache, step, positions, cfg):
 
 
 def _mlp(p, xn, cfg, dense: bool):
+    """The layer's MLP output and the rows its held experts' grouped
+    matmuls ran (0 in a dense layer)."""
     if dense:
-        return swiglu(xn, p["w_gate"], p["w_up"], p["w_down"])
+        return swiglu(xn, p["w_gate"], p["w_up"], p["w_down"]), jnp.int32(0)
     flat = xn.reshape(-1, xn.shape[-1])
-    return moe.apply_moe_share(p, flat, cfg=cfg).reshape(xn.shape)
+    out, rows = moe.apply_moe_share(p, flat, cfg=cfg)
+    return out.reshape(xn.shape), rows
 
 
 def _layers(params, x, attn, cfg, caches=None):
     """Dense layers, then a scan over the MoE layers.  ``attn(p, xn,
     cache)`` -> (out, new cache); ``caches`` = (dense, moe) per-layer
-    caches stacked like the weights (None where attn needs none)."""
+    caches stacked like the weights (None where attn needs none).
+    Returns (x, (dense, moe) new caches, the rows the MoE layers' grouped
+    matmuls ran, summed over the layers)."""
     def body(dense):
         def step(h, xs):
             p, cache = xs
             a, new = attn(p, rms_norm(h, p["ln1"], cfg.norm_eps), cache)
             h = h + a
-            h = h + _mlp(p, rms_norm(h, p["ln2"], cfg.norm_eps), cfg, dense)
-            return h, new
+            m, rows = _mlp(p, rms_norm(h, p["ln2"], cfg.norm_eps), cfg, dense)
+            return h + m, (new, rows)
         return step
     dense_c, moe_c = caches if caches is not None else (None, None)
-    x, new_dense = jax.lax.scan(body(True), x, (params["dense"], dense_c))
-    x, new_moe = jax.lax.scan(body(False), x, (params["moe"], moe_c))
-    return x, (new_dense, new_moe)
+    x, (new_dense, _) = jax.lax.scan(body(True), x, (params["dense"], dense_c))
+    x, (new_moe, rows) = jax.lax.scan(body(False), x, (params["moe"], moe_c))
+    return x, (new_dense, new_moe), rows.sum()
 
 
 def _concat_layers(dense, moe_part):
@@ -394,20 +399,21 @@ def forward(params, x, cfg):
     def attn(p, xn, _):
         out, _, _ = attn_prefill(p, xn, positions, cfg)
         return out, None
-    h, _ = _layers(params, x, attn, cfg)
+    h, _, _ = _layers(params, x, attn, cfg)
     return head(params, h, cfg)
 
 
 def prefill(params, x, cfg):
     """x (B, S, D) float32 embeddings -> (logits of the last position
-    (B, V), cache {"c": (L, B, S, R), "k_pe": (L, B, S, dr)} bfloat16)."""
+    (B, V), cache {"c": (L, B, S, R), "k_pe": (L, B, S, dr)} bfloat16,
+    the rows the MoE layers' grouped matmuls ran)."""
     positions = jnp.arange(x.shape[1])
 
     def attn(p, xn, _):
         out, c, k_pe = attn_prefill(p, xn, positions, cfg)
         return out, {"c": c, "k_pe": k_pe}
-    h, (dense, moe_part) = _layers(params, x, attn, cfg)
-    return head(params, h[:, -1], cfg), _concat_layers(dense, moe_part)
+    h, (dense, moe_part), rows = _layers(params, x, attn, cfg)
+    return head(params, h[:, -1], cfg), _concat_layers(dense, moe_part), rows
 
 
 def branch_cache(cfg: ArchConfig, cache: dict, branches: int,
@@ -425,36 +431,39 @@ def branch_cache(cfg: ArchConfig, cache: dict, branches: int,
 def decode_step(params, tokens, cache, step, cfg):
     """tokens (B, C) int32, the next token of each branch; ``step`` the
     slot it takes (its position is the prompt's length + step).  Returns
-    (float32 logits (B, C, V), the cache with that slot written)."""
+    (float32 logits (B, C, V), the cache with that slot written, the rows
+    the MoE layers' grouped matmuls ran)."""
     positions = cache["c"].shape[2] + step
     x = embed(params, tokens)
 
     def attn(p, xn, layer_cache):
         out, bc, bk = attn_decode(p, xn, layer_cache, step, positions, cfg)
         return out, {"bc": bc, "bk_pe": bk}
-    h, (dense, moe_part) = _layers(params, x, attn, cfg,
-                                   caches=_split_layers(cache, cfg))
+    h, (dense, moe_part), rows = _layers(params, x, attn, cfg,
+                                         caches=_split_layers(cache, cfg))
     new = _concat_layers(dense, moe_part)
-    return head(params, h, cfg), {**cache, **new}
+    return head(params, h, cfg), {**cache, **new}, rows
 
 
 # ------------------------------------------------------ answer scoring
 def score_prefill(params, images, ids, steps, *, cfg):
     """Prefill images (B, H, W, C) and the prompt, ``ids`` = (prompt (P,),
     answers (C, T)); returns (log-probability of each answer's first
-    token (B, C), the cache with room for ``steps`` tokens a branch)."""
+    token (B, C), the cache with room for ``steps`` tokens a branch, the
+    rows the MoE layers' grouped matmuls ran)."""
     prompt, answers = ids
-    logits, cache = prefill(params, embed_inputs(params, images, prompt, cfg),
-                            cfg)
+    logits, cache, rows = prefill(
+        params, embed_inputs(params, images, prompt, cfg), cfg)
     lp = log_probs(logits[:, None], answers[None, :, 0])
-    return lp, branch_cache(cfg, cache, answers.shape[0], steps)
+    return lp, branch_cache(cfg, cache, answers.shape[0], steps), rows
 
 
 def score_decode(params, cache, answers, step, *, cfg):
     """Feed each answer's token ``step`` to its branch; returns (the
-    log-probability of its token ``step + 1`` (B, C), the cache)."""
+    log-probability of its token ``step + 1`` (B, C), the cache, the rows
+    the MoE layers' grouped matmuls ran)."""
     B = cache["c"].shape[1]
     fed = jnp.broadcast_to(answers[:, step], (B, answers.shape[0]))
     nxt = jnp.broadcast_to(answers[:, step + 1], fed.shape)
-    logits, cache = decode_step(params, fed, cache, step, cfg)
-    return log_probs(logits, nxt), cache
+    logits, cache, rows = decode_step(params, fed, cache, step, cfg)
+    return log_probs(logits, nxt), cache, rows

@@ -240,19 +240,44 @@ def route_sigmoid_noaux_tc(x, router, bias, *, k: int, norm: bool,
     return w * scale, experts
 
 
-def apply_moe_share(p: dict, x: jax.Array, *, cfg: ArchConfig) -> jax.Array:
+def row_tiers(tokens: int, k: int, n_held: int, n_experts: int
+              ) -> tuple[int, ...]:
+    """The row counts, ascending, that one MoE layer's held-expert part
+    may run over for ``tokens`` tokens of ``k`` assignments each: 2x and
+    4x the rows the held share ``n_held / n_experts`` of the T*k
+    assignments expects, rounded up to 128 rows, and last the most that
+    can be held, ``tokens * min(k, n_held)`` (a token picks an expert at
+    most once), so that no routing drops an assignment.  Where this chip
+    holds every expert, that last count alone."""
+    cap = tokens * min(k, n_held)
+    tiers = {cap}
+    for m in (2, 4):
+        rows = -(-tokens * k * m * n_held // n_experts)
+        tiers.add(min(cap, -(-rows // 128) * 128))
+    return tuple(sorted(tiers))
+
+
+def apply_moe_share(p: dict, x: jax.Array, *, cfg: ArchConfig
+                    ) -> tuple[jax.Array, jax.Array]:
     """One MoE layer's output on this chip: the held experts' part of the
     routed sum plus the shared experts, for x (T, D) float32 -> (T, D)
-    float32.  Routing is over all ``cfg.num_experts``; a token's
-    assignments to experts held elsewhere add nothing here.  No token is
-    dropped: the held assignments are sorted by expert and run as one
-    grouped (ragged) matmul per projection, whose groups are exactly as
-    long as the tokens routed to each expert.
+    float32, and the rows the held experts' grouped matmuls ran (int32).
+    Routing is over all ``cfg.num_experts``; a token's assignments to
+    experts held elsewhere add nothing here.  No token is dropped: the
+    assignments are sorted held first, by expert, and the held ones run
+    as one grouped (ragged) matmul per projection, whose groups are
+    exactly as long as the tokens routed to each expert.
+
+    The gather, the grouped matmuls, the weighting and the scatter run
+    over the first rows of that order only: the least of
+    :func:`row_tiers` that covers this layer's held assignments, chosen
+    on the device.  Rows within that tier past the held groups are
+    masked out.
 
     ``p``: ``router`` (D, E), ``bias`` (E,), the held experts' ``w_gate``,
     ``w_up`` (E_h, D, F), ``w_down`` (E_h, F, D), and the shared experts
     merged into one MLP, ``s_gate``, ``s_up`` (D, F_s), ``s_down``."""
-    T, _ = x.shape
+    T, D = x.shape
     K = cfg.num_experts_per_tok
     n_held = p["w_gate"].shape[0]
     w, experts = route_sigmoid_noaux_tc(
@@ -262,23 +287,34 @@ def apply_moe_share(p: dict, x: jax.Array, *, cfg: ArchConfig) -> jax.Array:
     held = (local >= 0) & (local < n_held)
     slot = jnp.where(held, local, n_held).reshape(-1)        # n_held: elsewhere
     order = jnp.argsort(slot, stable=True)
-    token_of = order // K
     sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
     dt = p["w_gate"].dtype
-    xb = x.astype(dt)[token_of]
+    xb = x.astype(dt)
+    w = w.reshape(-1)
 
-    def grouped(lhs, rhs):
-        return jax.lax.ragged_dot(lhs, rhs, sizes,
-                                  preferred_element_type=jnp.float32)
-    h = jax.nn.silu(grouped(xb, p["w_gate"])) * grouped(xb, p["w_up"])
-    y = grouped(h.astype(dt), p["w_down"])
-    # rows past the held groups are not computed: keep them out
-    mine = (slot[order] < n_held)[:, None]
-    y = jnp.where(mine, y * w.reshape(-1)[order][:, None], 0.0)
-    routed = jnp.zeros((T, x.shape[1]), jnp.float32).at[token_of].add(y)
-    xs = x.astype(dt)
-    shared = jax.nn.silu(jnp.dot(xs, p["s_gate"],
+    def routed(rows):
+        def run(_):
+            o = order[:rows]
+            token_of = o // K
+            lhs = xb[token_of]
+
+            def grouped(a, rhs):
+                return jax.lax.ragged_dot(a, rhs, sizes,
+                                          preferred_element_type=jnp.float32)
+            h = jax.nn.silu(grouped(lhs, p["w_gate"])) \
+                * grouped(lhs, p["w_up"])
+            y = grouped(h.astype(dt), p["w_down"])
+            mine = (slot[o] < n_held)[:, None]
+            y = jnp.where(mine, y * w[o][:, None], 0.0)
+            return jnp.zeros((T, D), jnp.float32).at[token_of].add(y)
+        return run
+
+    tiers = row_tiers(T, K, n_held, cfg.num_experts)
+    tier = jnp.searchsorted(jnp.asarray(tiers, jnp.int32), sizes.sum())
+    out = jax.lax.switch(tier, [routed(r) for r in tiers], None)
+    shared = jax.nn.silu(jnp.dot(xb, p["s_gate"],
                                  preferred_element_type=jnp.float32)) \
-        * jnp.dot(xs, p["s_up"], preferred_element_type=jnp.float32)
-    return routed + jnp.dot(shared.astype(dt), p["s_down"],
-                            preferred_element_type=jnp.float32)
+        * jnp.dot(xb, p["s_up"], preferred_element_type=jnp.float32)
+    out = out + jnp.dot(shared.astype(dt), p["s_down"],
+                        preferred_element_type=jnp.float32)
+    return out, jnp.asarray(tiers, jnp.int32)[tier]

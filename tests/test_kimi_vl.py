@@ -10,6 +10,7 @@ reads 0.006-0.008 here, the reference computed in bfloat16 0.04-0.06)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -75,12 +76,12 @@ def _want(ref, recipe, images, spec=SPEC):
 def _program_scores(params, images, cfg):
     ids = (jnp.asarray(PROMPT, jnp.int32), jnp.asarray(ANSWERS, jnp.int32))
     steps = len(ANSWERS[0]) - 1
-    lp, cache = mla.score_prefill(params, jnp.asarray(images), ids, steps,
-                                  cfg=cfg)
+    lp, cache, _ = mla.score_prefill(params, jnp.asarray(images), ids,
+                                     steps, cfg=cfg)
     out = [lp]
     for t in range(steps):
-        lp, cache = mla.score_decode(params, cache, ids[1], jnp.int32(t),
-                                     cfg=cfg)
+        lp, cache, _ = mla.score_decode(params, cache, ids[1], jnp.int32(t),
+                                        cfg=cfg)
         out.append(lp)
     return np.stack(out, -1)
 
@@ -177,10 +178,10 @@ def test_absorbed_decode_agrees_with_the_expanded_form(recipe):
     x = jnp.asarray(rng.standard_normal((2, 7, cfg.d_model)), jnp.float32)
     nxt = jnp.asarray([[5, 6], [7, 8]], jnp.int32)      # (B, C) step 0
     after = jnp.asarray([[9, 10], [11, 12]], jnp.int32)  # step 1
-    _, cache = mla.prefill(params, x, cfg)
+    _, cache, _ = mla.prefill(params, x, cfg)
     cache = mla.branch_cache(cfg, cache, 2, 2)
-    lg0, cache = mla.decode_step(params, nxt, cache, jnp.int32(0), cfg)
-    lg1, _ = mla.decode_step(params, after, cache, jnp.int32(1), cfg)
+    lg0, cache, _ = mla.decode_step(params, nxt, cache, jnp.int32(0), cfg)
+    lg1, _, _ = mla.decode_step(params, after, cache, jnp.int32(1), cfg)
     for c in range(2):
         seq = jnp.concatenate(
             [x, jnp.take(params["embed"], jnp.stack([nxt[:, c], after[:, c]],
@@ -223,7 +224,8 @@ def test_moe_layer_agrees_with_the_reference(ref, recipe):
     layer = 2
     x = jnp.asarray(np.random.default_rng(2).standard_normal(
         (40, cfg.d_model)), jnp.float32)
-    got = moe.apply_moe_share(_moe_params(cfg, recipe, layer), x, cfg=cfg)
+    got, _ = moe.apply_moe_share(_moe_params(cfg, recipe, layer), x,
+                                 cfg=cfg)
     pre = f"layers.{layer}."
     w = {n[len(pre):]: jnp.asarray(ref._get(recipe, n, jax.devices()[0],
                                             jnp.float32))
@@ -243,22 +245,108 @@ def test_expert_shares_add_up_to_the_uncut_layer(ref):
     whole = from_hf(full_spec, experts_held=n_exp, patch_size=4)
     x = jnp.asarray(np.random.default_rng(4).standard_normal(
         (50, whole.d_model)), jnp.float32)
-    uncut = moe.apply_moe_share(_moe_params(whole, recipe, 1), x, cfg=whole)
+    uncut, _ = moe.apply_moe_share(_moe_params(whole, recipe, 1), x,
+                                   cfg=whole)
     held = n_exp // 4
     parts = []
     for chip in range(4):
         cfg = dataclasses.replace(whole, experts_held=held,
                                   first_held_expert=chip * held)
         parts.append(moe.apply_moe_share(_moe_params(cfg, recipe, 1), x,
-                                         cfg=cfg))
+                                         cfg=cfg)[0])
     p = _moe_params(whole, recipe, 1)
     no_routed = {**p, "w_down": jnp.zeros_like(p["w_down"])}
-    shared = moe.apply_moe_share(no_routed, x, cfg=whole)
+    shared, _ = moe.apply_moe_share(no_routed, x, cfg=whole)
     total = sum(parts) - 3 * shared
     np.testing.assert_allclose(total, uncut, atol=F32_TOL, rtol=0)
     # each share differs from the whole: the cut is real
     assert all(np.abs(np.asarray(part - uncut)).max() > 1e-3
                for part in parts)
+
+
+# 64 experts, top-6 as published, at the reduced widths; 400 tokens are
+# 2,400 assignments, whose tiers at 8 of 64 held are 640, 1,280, 2,400
+WIDE = {**SPEC, "n_routed_experts": 64, "num_experts_per_tok": 6}
+TOKENS = 400
+
+
+@functools.lru_cache(maxsize=None)
+def _wide(held):
+    ref = _load(os.path.join(ROOT, "bench", "references", "kimi_vl_tag.py"),
+                "kimi_vl_tag_reference")
+    spec = {**WIDE, "experts_held": held}
+    return spec, ref.weights(spec, SEED), from_hf(spec, experts_held=held,
+                                                  patch_size=4)
+
+
+@pytest.mark.parametrize("held,lifted,tier", [
+    (8, 0, 640), (8, 2, 1280), (8, 6, 2400), (64, 0, 2400)],
+    ids=["quarter", "half", "whole", "every_expert_held"])
+def test_moe_layer_runs_the_least_tier_that_holds_its_assignments(
+        ref, monkeypatch, held, lifted, tier):
+    """The held experts' part runs over the first rows of the held-first
+    order, the least tier that covers the held assignments, and gives
+    what the whole T x K rows give.  A bias that lifts ``lifted`` held
+    experts into every token's top 6 fills the tiers above the least:
+    with 6, every assignment is held and the whole tier runs, dropping
+    none.  A chip holding every expert has the whole tier alone, and
+    its program no branch."""
+    spec, recipe, cfg = _wide(held)
+    tiers = moe.row_tiers(TOKENS, 6, held, 64)
+    assert tiers == ((640, 1280, 2400) if held < 64 else (2400,))
+    p = _moe_params(cfg, recipe, 1)
+    p = {**p, "bias": p["bias"].at[:lifted].add(10.0)}
+    x = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (TOKENS, cfg.d_model)), jnp.float32)
+    _, experts = moe.route_sigmoid_noaux_tc(
+        x, p["router"], p["bias"], k=6, norm=True,
+        scale=cfg.routed_scaling_factor)
+    n_held = int((np.asarray(experts) < held).sum())
+    below = ([0] + [t for t in tiers if t < tier])[-1]
+    assert below < n_held <= tier
+    apply = functools.partial(moe.apply_moe_share, cfg=cfg)
+    assert ("cond" in str(jax.make_jaxpr(apply)(p, x))) == (len(tiers) > 1)
+    got, rows = apply(p, x)
+    assert int(rows) == tier
+    # the whole T x K rows, as every tier but the last leaves out
+    monkeypatch.setattr(moe, "row_tiers", lambda t, k, h, e: (t * k,))
+    whole, rows = apply(p, x)
+    assert int(rows) == TOKENS * 6
+    np.testing.assert_allclose(got, whole, atol=1e-6, rtol=0)
+    # and the reference's every-held-expert-masked sum
+    pre = "layers.1."
+    w = {n[len(pre):]: jnp.asarray(ref._get(recipe, n, jax.devices()[0],
+                                            jnp.float32))
+         for n in recipe if n.startswith(pre)}
+    w["mlp.e_score_correction_bias"] = p["bias"]
+    with jax.default_matmul_precision("highest"):
+        want = ref._moe(w, x, spec)
+    np.testing.assert_allclose(got, want, atol=F32_TOL, rtol=0)
+
+
+def test_the_device_udf_counts_the_moe_rows_it_ran(images):
+    """One call of the registered op over a group of three, padded to
+    four: the assignments are tokens x 6 x the MoE layers of the prefill
+    and the decode steps, padding included, and the rows run are fewer
+    where 8 of 64 experts are held."""
+    from repro.core.trace import Tracer
+    _, recipe, cfg = _wide(8)
+    tracer = Tracer()
+    udf.register_model_udf("kimi_rows", cfg, weights=recipe, tracer=tracer,
+                           buckets=(4,))
+    try:
+        udf.get_device_udf("kimi_rows")(
+            list(images), prompt=tuple(PROMPT),
+            candidates=tuple(map(tuple, ANSWERS)))
+    finally:
+        udf.unregister_udf("kimi_rows")
+    counts = tracer.stats()["counts"]
+    steps = len(ANSWERS[0]) - 1
+    prefix = 3 * 3 + len(PROMPT)        # 3x3 merged 8x8 patches, the prompt
+    tokens = 4 * (prefix + len(ANSWERS) * steps)
+    moe_layers = cfg.num_layers - cfg.first_k_dense_replace
+    assert counts["model_moe_assignments"] == tokens * 6 * moe_layers
+    assert 0 < counts["model_moe_rows"] < counts["model_moe_assignments"]
 
 
 
