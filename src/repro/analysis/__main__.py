@@ -39,7 +39,7 @@ def main(argv=None) -> int:
                     help="also write the full JSON report here")
     ap.add_argument("--dot", default=None,
                     help="write the lock-order graph as DOT here")
-    ap.add_argument("--ref-dirs", nargs="*", default=["tests", "benchmarks"],
+    ap.add_argument("--ref-dirs", nargs="*", default=["tests", "bench"],
                     help="dirs scanned for knob references")
     args = ap.parse_args(argv)
 

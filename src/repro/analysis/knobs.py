@@ -5,8 +5,8 @@ The public constructors (``VDMSAsyncEngine``, ``ShardedEngine``,
 every knob (1) is a keyword argument with a default, (2) defaults to
 the *inert* value — the paper-faithful path must be byte-identical
 with all knobs at their defaults — and (3) is exercised by name in at
-least one test or benchmark, so the default-off path stays pinned by
-the hash-gated baselines.
+least one test or benchmark cell, so the default-off path stays pinned
+by the hash-gated tests (``tests/test_response_identity.py``).
 
 Statically checkable slice:
 
@@ -14,8 +14,8 @@ Statically checkable slice:
   forced to think about — violates (1);
 * a boolean knob defaulting to ``True`` is an *enabling* default and
   violates (2) (deliberate exceptions carry a waiver);
-* a knob whose name appears nowhere under ``tests/`` or
-  ``benchmarks/`` violates (3) — nothing pins its default-off path.
+* a knob whose name appears nowhere under ``tests/`` or ``bench/``
+  violates (3) — nothing pins its default-off path.
 
 Positional parameters without defaults (``engine``, required wiring)
 are dependencies, not knobs, and are skipped.

@@ -4,8 +4,6 @@ architectures with ``repro.configs.base``; select one with
 """
 from repro.configs.base import (  # noqa: F401
     ArchConfig,
-    ShapeConfig,
-    SHAPES,
     get_arch,
     list_archs,
 )

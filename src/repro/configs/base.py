@@ -1,13 +1,8 @@
-"""Architecture + shape configuration.
+"""Architecture configuration.
 
 One ``ArchConfig`` per assigned architecture lives in
 ``repro/configs/<id>.py`` with the *exact* published dimensions; each also
 provides ``reduced()`` — a same-family shrunken config for CPU smoke tests.
-
-Shapes are the four assigned input-shape cells.  ``train_*`` lowers
-``train_step``; ``prefill_*`` lowers the prefill half of serving;
-``decode_*`` / ``long_*`` lower ``serve_step`` (one new token against a KV
-cache of ``seq_len``).
 """
 from __future__ import annotations
 
@@ -18,26 +13,6 @@ from typing import Any, Mapping, Optional, Sequence
 
 def pad_to(x: int, multiple: int) -> int:
     return int(math.ceil(x / multiple) * multiple)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-    @property
-    def is_decode(self) -> bool:
-        return self.kind == "decode"
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,16 +165,6 @@ class ArchConfig:
     @property
     def rwkv_nheads(self) -> int:
         return self.d_model // self.rwkv_head_dim
-
-    def supports_shape(self, shape: ShapeConfig) -> tuple[bool, str]:
-        """Whether a cell (arch x shape) is runnable; reason if not.
-
-        ``long_500k`` requires sub-quadratic sequence mixing; pure
-        full-attention archs skip it (documented in DESIGN.md).
-        """
-        if shape.name == "long_500k" and self.attention == "full":
-            return False, "full O(L^2) attention infeasible at 524288; skipped by design"
-        return True, ""
 
     def param_count(self) -> int:
         """Analytic parameter count (embedding included, unpadded vocab)."""

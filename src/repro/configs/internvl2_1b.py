@@ -2,8 +2,8 @@
 
 24L d_model=896 14H (GQA kv=2) d_ff=4864 vocab=151655.
 [arXiv:2404.16821; hf]  The vision tower is a stub per the assignment:
-``input_specs()`` supplies precomputed patch embeddings (already projected
-to d_model) which are prepended to the token embeddings.
+the model takes precomputed patch embeddings (already projected to
+d_model), which are prepended to the token embeddings.
 """
 from repro.configs.base import ArchConfig, register
 

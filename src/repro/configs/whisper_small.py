@@ -1,9 +1,9 @@
 """whisper-small [audio] — encoder-decoder; conv frontend is a STUB.
 
 12L (enc) + 12L (dec) d_model=768 12H (kv=12) d_ff=3072 vocab=51865.
-[arXiv:2212.04356; unverified]  ``input_specs()`` supplies precomputed
-mel-frame embeddings (post conv-frontend, 1500 x d_model) per the
-assignment; positions are sinusoidal so arbitrary cache lengths lower.
+[arXiv:2212.04356; unverified]  The model takes precomputed mel-frame
+embeddings (post conv-frontend, 1500 x d_model) per the assignment;
+positions are sinusoidal so arbitrary cache lengths lower.
 """
 from repro.configs.base import ArchConfig, register
 

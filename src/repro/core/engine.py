@@ -58,10 +58,7 @@ class VDMSAsyncEngine:
       remote executors), each a worker thread with a calibrated
       transport model.  ``transport``: a
       :class:`~repro.core.remote.TransportModel` (network latency,
-      bandwidth, per-entity service time).  ``dispatch_policy``:
-      ``"round_robin"`` | ``"least_loaded"`` server picker (NOT the
-      multi-backend ``dispatch`` knob below).  ``batch_remote``:
-      coalesce up to N same-op entities per remote request.
+      bandwidth, per-entity service time).
 
     **Scheduling** (not paper-faithful by default; the exact paper
     baseline is ``num_native_workers=1, fair_scheduling=False``) —
@@ -161,8 +158,6 @@ class VDMSAsyncEngine:
     def __init__(self, *, num_remote_servers: int = 1,
                  transport: TransportModel | None = None,
                  fuse_native: bool = False,
-                 batch_remote: int = 1,
-                 dispatch_policy: str = "round_robin",
                  num_native_workers: int | None = None,
                  # analysis: ok(knob-inert) — deliberate: FIFO starvation is a known seed defect; fairness-off is the opt-out
                  fair_scheduling: bool = True,
@@ -377,7 +372,6 @@ class VDMSAsyncEngine:
         self.erd = ERD()
         self.pool = RemoteServerPool(
             num_remote_servers, transport,
-            policy=dispatch_policy,
             max_retries=max_retries,
             retry_backoff_base_s=retry_backoff_base_s,
             retry_backoff_max_s=retry_backoff_max_s,
@@ -424,7 +418,6 @@ class VDMSAsyncEngine:
                     self.device_backend.fault_injector = fault_injector
         self.loop = EventLoop(self.pool, self.erd,
                               fuse_native=fuse_native,
-                              batch_remote=batch_remote,
                               num_native_workers=self.num_native_workers,
                               fair_scheduling=fair_scheduling,
                               on_entity_done=self._entity_done,
@@ -756,9 +749,8 @@ class VDMSAsyncEngine:
         ``compiles`` + bounded-cache ``jit_entries``/``jit_evictions``,
         calibration state, ``h2d_bytes``/``d2h_bytes`` moved,
         ``padding_waste_frac``, and — with ``num_device_workers > 1``
-        — a ``per_device`` breakdown) when those backends exist.  ``{"mode": "static"}`` alone when the router is off
-        (not to be confused with ``dispatch_policy``, the remote pool's
-        round-robin/least-loaded server picker)."""
+        — a ``per_device`` breakdown) when those backends exist.
+        ``{"mode": "static"}`` alone when the router is off."""
         out: dict = {"mode": self.dispatch}
         if self.router is not None:
             out.update(self.router.stats())

@@ -28,12 +28,8 @@ timed-out query drains instead of orphaning work.
 
 Beyond-paper knobs, default OFF:
 - ``fuse_native``:   jit-fuse maximal native-op runs (one dispatch per run);
-- ``batch_remote``:  coalesce up to N same-op entities per remote request,
-                     amortizing per-request network latency (per-buffer:
-                     whatever happens to sit in Thread_3's buffer at flush
-                     time);
 - ``coalesce_window_s``: cross-session request coalescing.  Instead of
-  flushing Thread_3's buffer wholesale, pending remote work is grouped by
+  dispatching each entity as it arrives, pending remote work is grouped by
   op signature (which pins the endpoint, so a group maps to one batched
   request on one server); each group is held open for the window from its
   first member's arrival — or until ``coalesce_max_batch`` — then
@@ -316,7 +312,6 @@ class FairQueue:
 class EventLoop:
     def __init__(self, pool: RemoteServerPool, erd: ERD, *,
                  fuse_native: bool = False,
-                 batch_remote: int = 1,
                  num_native_workers: int = 1,
                  fair_scheduling: bool = True,
                  on_entity_done: Optional[Callable[[Entity], None]] = None,
@@ -346,7 +341,6 @@ class EventLoop:
         self._pool_tick = getattr(pool, "tick", None)
         self._pool_next_due = getattr(pool, "next_retry_due", None)
         self.fuse_native = fuse_native
-        self.batch_remote = max(1, batch_remote)
         self.coalesce_window_s = max(0.0, coalesce_window_s)
         self.coalesce_max_batch = max(2, coalesce_max_batch)
         self.result_cache = result_cache
@@ -523,7 +517,6 @@ class EventLoop:
 
     # ------------------------------------------------------- Thread_3 loop
     def _thread3(self):
-        pending: list[Entity] = []  # dispatch batching buffer (window off)
         # coalescing-window state lives on self (_groups/_deadlines): one
         # open group per op signature, deadline set by its FIRST member's
         # arrival (self._clock-based so tests can inject a time source)
@@ -583,11 +576,8 @@ class EventLoop:
                         self._buffered += 1
                         if len(group) >= self.coalesce_max_batch:
                             self._flush_groups([op])
-                    else:
-                        pending.append(ent)
-                        if len(pending) >= self.batch_remote:
-                            self._flush(pending)
-                            pending = []
+                    elif not self.is_cancelled(ent.query_id):
+                        self._dispatch_remote(ent, ent.current_op())
                 elif kind in ("batched", "device"):
                     # offload-backend group reply (batcher or device):
                     # same handoff semantics as a remote response.
@@ -606,14 +596,6 @@ class EventLoop:
                     # R-UDF-Response callback
                     tag, req, payload = msg
                     self._handle_response(tag, req, payload)
-                    if pending:
-                        self._flush(pending)
-                        pending = []
-                self.t3_meter.stop()
-            elif pending:
-                self.t3_meter.start()
-                self._flush(pending)
-                pending = []
                 self.t3_meter.stop()
             if self._deadlines:
                 now = self._clock()
@@ -637,22 +619,6 @@ class EventLoop:
         self.coalesced_batches += 1
         self.coalesced_entities += len(group)
         self._dispatch_remote(group, group[0].current_op())
-
-    def _flush(self, entities: list[Entity]):
-        """Q2-Enqueue handling: dispatch entities' current ops (grouped
-        into one batched request per op when batch_remote > 1).  Entities
-        of queries cancelled while they sat in the buffer are dropped."""
-        entities = [e for e in entities if not self.is_cancelled(e.query_id)]
-        if self.batch_remote > 1:
-            groups: dict[Any, list[Entity]] = {}
-            for e in entities:
-                groups.setdefault(e.current_op(), []).append(e)
-            for op, group in groups.items():
-                payload = group if len(group) > 1 else group[0]
-                self._dispatch_remote(payload, op)
-        else:
-            for e in entities:
-                self._dispatch_remote(e, e.current_op())
 
     def _dispatch_remote(self, payload, op):
         """``pool.dispatch`` with Thread_3 protected from a pool-level

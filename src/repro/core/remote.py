@@ -129,8 +129,7 @@ class RemoteServer:
 
     def load(self) -> int:
         # entities, not requests: a k-entity coalesced batch is k units of
-        # pending work, so least_loaded dispatch stays balanced when
-        # batched and per-entity requests mix
+        # pending work
         with self._pending_lock:
             return self._pending
 
@@ -262,11 +261,11 @@ class RemoteServer:
 
 
 class RemoteServerPool:
-    """kappa servers + dispatch policy + retry/straggler/health logic."""
+    """kappa servers + round-robin dispatch + retry/straggler/health
+    logic."""
 
     def __init__(self, num_servers: int = 1,
                  transport: TransportModel | None = None,
-                 policy: str = "round_robin",
                  max_retries: int = 3,
                  straggler_factor: float = 4.0,
                  retry_backoff_base_s: float = 0.0,
@@ -276,7 +275,6 @@ class RemoteServerPool:
                  tracer: Tracer | None = None):
         self.transport = transport or TransportModel()
         self.tracer = tracer or Tracer()
-        self.policy = policy
         self.max_retries = max_retries
         self.straggler_factor = straggler_factor
         self.retry_backoff_base_s = max(0.0, retry_backoff_base_s)
@@ -366,8 +364,6 @@ class RemoteServerPool:
             raise NoLiveServersError("no live remote servers")
         if exclude is not None and len(live) > 1:
             live = [s for s in live if s.sid != exclude] or live
-        if self.policy == "least_loaded":
-            return min(live, key=lambda s: s.load())
         return live[next(self._rr) % len(live)]
 
     def dispatch(self, entity, op: Operation, reply_to: queue.Queue) -> int:

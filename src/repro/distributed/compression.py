@@ -1,11 +1,10 @@
 """Gradient compression for the data-parallel reduction.
 
-Two mechanisms, both verifiable in the dry-run HLO:
+Two mechanisms:
 
 1. bf16 gradient reduction (default ON via TrainConfig.grad_reduce_dtype):
    the backward emits bf16 gradients, so GSPMD's DP all-reduce moves half
-   the bytes.  Zero code here — it falls out of dtype flow — but the
-   collective-bytes delta shows up in EXPERIMENTS.md section Perf.
+   the bytes.  Zero code here — it falls out of dtype flow.
 
 2. int8 + error feedback (this module): for pure-DP meshes (model
    replicated, e.g. the paper-style "kappa remote servers" scale-out),

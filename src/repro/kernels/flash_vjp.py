@@ -7,7 +7,7 @@ train_4k).  The custom VJP saves only (q, k, v, out, lse) and recomputes
 probability blocks in the backward pass (FlashAttention-2 scheme), block
 pair by block pair via dynamic slices, so both passes are O(block^2)
 memory.  This is the same math the Pallas TPU kernel implements; XLA
-lowers this form on any backend, and the dry-run roofline reflects it.
+lowers this form on any backend.
 
 GQA is handled by grouping q-heads per kv-head (no materialized repeat).
 """

@@ -2,8 +2,8 @@
 
 These are the ground truth the kernels are validated against
 (tests/test_kernels.py sweeps shapes/dtypes with assert_allclose), and
-they are also the execution path on non-TPU backends — the dry-run
-lowers these, so compiled FLOPs match the kernel math.
+they are also the execution path on non-TPU backends, so compiled
+FLOPs match the kernel math.
 """
 from __future__ import annotations
 
@@ -67,8 +67,8 @@ def flash_attention_jnp(
 ) -> jax.Array:
     """Online-softmax attention, O(q_block * kv_block) logits memory.
 
-    Same math as the Pallas flash kernel; this is what the dry-run lowers
-    on the CPU backend and what long-sequence prefill uses under jit.
+    Same math as the Pallas flash kernel; this is what the CPU backend
+    lowers and what long-sequence prefill uses under jit.
     """
     B, Sq, H, D = q.shape
     _, Sk, Hkv, _ = k.shape

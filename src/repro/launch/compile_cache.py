@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
-Every entry point (``chip_smoke.py``, ``repro.launch.serve``,
-``repro.launch.model_serve``, the ``benchmarks`` mains) calls
+Every entry point (``bench/run.py`` through its harness,
+``repro.launch.serve``, ``repro.launch.model_serve``) calls
 :func:`use_compile_cache` before it compiles anything.
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and this sets

@@ -6,8 +6,8 @@ axis is the outer data-parallel axis (crosses DCI) and realizes the
 paper's "add another rack of remote servers" scale-out dimension.
 
 These are FUNCTIONS so importing this module never touches jax device
-state (device count is locked at first jax init; the dry-run sets
-XLA_FLAGS before importing anything).
+state (device count is locked at first jax init, so a caller that
+wants virtual devices sets XLA_FLAGS before importing anything).
 """
 from __future__ import annotations
 

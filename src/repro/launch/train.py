@@ -3,11 +3,11 @@
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
-Full production shapes go through the same path with ``--mesh production``
-(that is what the dry-run compiles); on this CPU container use reduced
-configs and the host mesh.  Features: sharded init, pjit train step with
-microbatching, WSD/cosine schedules, prefetching loader, periodic atomic
-checkpoints, automatic restart from the latest checkpoint.
+Full production shapes go through the same path with ``--mesh
+production``; on a CPU host use reduced configs and the host mesh.
+Features: sharded init, pjit train step with microbatching, WSD/cosine
+schedules, prefetching loader, periodic atomic checkpoints, automatic
+restart from the latest checkpoint.
 """
 from __future__ import annotations
 

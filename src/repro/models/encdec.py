@@ -1,6 +1,6 @@
 """Whisper-style encoder-decoder.  The conv/mel frontend is a STUB per
 the assignment: the encoder consumes precomputed frame embeddings
-(B, Se, d) supplied by ``input_specs()``."""
+(B, Se, d)."""
 from __future__ import annotations
 
 import jax

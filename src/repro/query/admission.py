@@ -6,8 +6,7 @@ paper's event-driven pipeline scales linearly with remote servers only
 while its queues stay bounded, and under heavy fan-in Queue_1/Queue_2
 and the coalescing/device micro-batch buffers grow without limit until
 latency collapses (the synchronous-saturation failure mode VDMS-Async
-was designed to escape, reproduced by ``benchmarks/admission_bench.py``'s
-unbounded arm).  This module bounds the engine instead:
+was designed to escape).  This module bounds the engine instead:
 
 - an :class:`AdmissionController` tracks the number of **in-flight
   entities** (launched onto the event loop but not yet completed,
@@ -39,13 +38,13 @@ pending depth weighted by its amortized latency estimate
 (:meth:`repro.core.remote.RemoteServerPool.backlog_seconds`), and the
 batcher/device micro-batch queue depths — into one number (≥ 1.0 means
 saturated).  The *admission decision* is exact on the in-flight ledger
-(that is the invariant benchmarks assert); the score feeds the
+(that is the invariant the tests assert); the score feeds the
 ``retry_after_s`` estimate, the saturation fast path that rejects
 before a phase is even expanded, and ``engine.admission_stats()``.
 
 ``admission="none"`` (the default) builds none of this: ``submit()``
-behaves byte-identically to the unbounded engine (hash-checked in CI
-via ``benchmarks/admission_bench.py``).
+behaves byte-identically to the unbounded engine (its response hash is
+pinned in ``tests/test_response_identity.py``).
 """
 from __future__ import annotations
 

@@ -11,8 +11,7 @@ group drains, then the next group is admitted — iteration-level, not
 token-level, admission; the difference vs. vLLM-style slot reuse is
 documented and the engine never blocks on it because groups are small).
 
-Throughput accounting (`tokens_out / steps_run`) is what
-benchmarks/serving_bench.py reports.
+Throughput accounting: `tokens_out / steps_run`.
 
 ``UDFBatcherBackend`` promotes this layer to a first-class *dispatch
 backend* behind the common ``repro.query.dispatch.Backend`` protocol:

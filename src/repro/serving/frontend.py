@@ -25,8 +25,8 @@ One connection multiplexes any number of concurrent queries; frames
 interleave across queries but stay ordered within one (``submitted``
 → ``entity``* → terminal), which is what lets
 :func:`repro.serving.wire.reassemble` rebuild the in-process response
-dict byte-for-byte (hash-gated against the static baseline in
-``benchmarks/frontend_bench.py``).
+dict byte-for-byte (hash-gated against the static response's pinned
+sha256 in ``tests/test_response_identity.py``).
 
 ``WireClient`` is the reference client: ``execute()`` for blocking
 calls, ``submit()`` for a future-like handle with streamed frames

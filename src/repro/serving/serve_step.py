@@ -1,6 +1,6 @@
-"""Serving steps: prefill + single-token decode (the ``serve_step`` the
-decode_* / long_* dry-run cells lower), plus a small generate loop used
-by the examples and the query engine's model-UDF executor."""
+"""Serving steps: prefill + single-token decode (``serve_step``), plus a
+small generate loop used by the examples and the query engine's
+model-UDF executor."""
 from __future__ import annotations
 
 import functools

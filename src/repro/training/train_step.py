@@ -4,9 +4,9 @@ AdamW, and dtype-controlled DP gradient reduction.
 Mixed precision: master params are f32; the forward/backward runs in
 ``compute_dtype`` (bf16 on TPU).  Gradients come out of the backward in
 ``grad_reduce_dtype`` where safe — under GSPMD the DP all-reduce then
-moves half the bytes, which is the "gradient compression" knob verified
-in the dry-run HLO (EXPERIMENTS.md section Perf).  int8+error-feedback
-compression for pure-DP meshes lives in distributed/compression.py.
+moves half the bytes, which is the "gradient compression" knob.
+int8+error-feedback compression for pure-DP meshes lives in
+distributed/compression.py.
 """
 from __future__ import annotations
 

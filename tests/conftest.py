@@ -1,6 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests and benches see the
 single real CPU device; multi-device behaviour is tested via subprocesses
-(tests/test_distributed.py) and the dry-run launcher."""
+(tests/test_distributed.py)."""
 import os
 import sys
 

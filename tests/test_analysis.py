@@ -447,7 +447,7 @@ def test_shipped_tree_is_clean_against_baseline(monkeypatch):
     gate CI runs — and the baseline must be EMPTY: every true positive
     was fixed or waived, not baselined."""
     monkeypatch.chdir(REPO)
-    res = run_analysis(["src"], ref_dirs=["tests", "benchmarks"])
+    res = run_analysis(["src"], ref_dirs=["tests", "bench"])
     baseline = load_baseline("analysis_baseline.json")
     new, stale = check_baseline(res, baseline)
     assert [f.render() for f in new] == []

@@ -99,7 +99,7 @@ def test_two_servers_share_one_program_per_signature():
     op = make_op("grayscale", where="remote")
     imgs = _images(40, (29, 31, 3), seed=1)
     ents = [Entity(str(i), "image", im) for i, im in enumerate(imgs)]
-    pool = RemoteServerPool(2, NO_WAIT, policy="round_robin")
+    pool = RemoteServerPool(2, NO_WAIT)
     try:
         info0 = _fused_chain.cache_info()
         got = _serve(pool, ents, op)
